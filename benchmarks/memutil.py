@@ -9,8 +9,7 @@ the (n, m) window dominate and everything else is n-sized.
 ``SolveServer`` dispatches (``serve.server._coalesced_solve``) and reads
 XLA's ``memory_analysis`` (transient temps + arguments + outputs).
 Backends without the analysis fall back to ``cost_analysis``'s
-``bytes accessed`` estimate — normalising the list-vs-dict return shape
-older jaxlib versions use — and backends with neither report ``None``,
+``bytes accessed`` estimate, and backends with neither report ``None``,
 so rows stay null rather than carry a made-up number.
 """
 from __future__ import annotations
@@ -21,9 +20,8 @@ __all__ = ["compiled_bytes", "lowered_peak_bytes", "peak_for_row",
 
 def compiled_bytes(compiled):
     """Peak bytes of a compiled executable: temps + arguments + outputs
-    from ``memory_analysis``, else ``cost_analysis``'s ``bytes accessed``
-    (one dict, or a one-dict-per-device list on older jaxlib), else
-    ``None``."""
+    from ``memory_analysis``, else ``cost_analysis``'s ``bytes accessed``,
+    else ``None``."""
     try:
         ma = compiled.memory_analysis()
     except Exception:
@@ -38,8 +36,6 @@ def compiled_bytes(compiled):
         ca = compiled.cost_analysis()
     except Exception:
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     if isinstance(ca, dict) and ca.get("bytes accessed"):
         return int(ca["bytes accessed"])
     return None

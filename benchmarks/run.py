@@ -5,7 +5,9 @@ framework-level harnesses. Prints ``name,us_per_call,derived`` CSV; with
 config, peak_mem_bytes) so the perf trajectory is tracked across PRs.
 
 ``--tiny`` shrinks every shape to CI-smoke size (seconds, not minutes);
-``--full`` runs the exact paper grid.
+``--full`` runs the exact paper grid. ``--fleet`` adds the multi-process
+fleet section, which spawns worker subprocesses: it is refused on a TPU
+host, where this process holds the chip and the workers could not open it.
 """
 from __future__ import annotations
 
@@ -57,6 +59,15 @@ def main(argv=None) -> None:
     full = "--full" in argv
     tiny = "--tiny" in argv
     as_json = "--json" in argv
+    fleet = "--fleet" in argv
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+    if fleet:
+        import jax
+
+        from repro.fleet import refuse_on_tpu_parent
+        jax.devices()
+        refuse_on_tpu_parent("benchmarks/run.py --fleet")
     print("name,us_per_call,derived")
 
     solver_rows = []
@@ -125,15 +136,18 @@ def main(argv=None) -> None:
     serve_dist.run(emit=emit, assert_ratio=not tiny, **sv)
     serve_rows += rows
 
-    from benchmarks import serve_fleet
-    fv = dict(n=64, m=2_000, requests=16, k=4) if tiny \
-        else dict(n=512, m=25_000, requests=48, k=8)
-    rows, emit = _collector({"section": "serve_fleet", **fv}, peaks=peaks)
-    # subprocess workers + real sockets: the >=1.5x 2-worker scaling gate
-    # runs at the real shape on >=4-core hosts; reconciled-agreement
-    # asserts run at every shape, and all rows are trend-guarded.
-    serve_fleet.run(emit=emit, assert_ratio=not tiny, **fv)
-    serve_rows += rows
+    if fleet:
+        from benchmarks import serve_fleet
+        fv = dict(n=64, m=2_000, requests=16, k=4) if tiny \
+            else dict(n=512, m=25_000, requests=48, k=8)
+        rows, emit = _collector({"section": "serve_fleet", **fv},
+                                peaks=peaks)
+        # subprocess workers + real sockets: the >=1.5x 2-worker scaling
+        # gate runs at the real shape on >=4-core hosts; reconciled-
+        # agreement asserts run at every shape, and all rows are
+        # trend-guarded.
+        serve_fleet.run(emit=emit, assert_ratio=not tiny, **fv)
+        serve_rows += rows
 
     from benchmarks import serve_tenants
     tv = dict(n=64, m=2_000, rank=4, tenants=96, resident_cap=16,

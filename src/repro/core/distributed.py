@@ -162,11 +162,8 @@ def sharded_blocked_chol_solve(S, v_blocks, damping, *,
     per-block right-hand sides; the result keeps block structure and
     sharding, so a per-layer optimizer applies it with zero re-sharding.
 
-    Consume the result per block (elementwise / gather). Known caveat:
-    ``jnp.concatenate`` across the returned blocks mis-reshards on some
-    jaxlib 0.4 CPU builds (replication over the unmentioned data axis is
-    turned into a sum) — and concatenating would defeat the blocked
-    representation anyway.
+    Consume the result per block (elementwise / gather): concatenating
+    would defeat the blocked representation.
     """
     from repro.core.operator import BlockedScores, LazyBlockedScores
 

@@ -1,32 +1,19 @@
-"""Version-compatible ``shard_map`` — the one place the probe lives.
-
-jax moved ``shard_map`` twice across the versions this repo supports:
-
-* jax >= 0.6: top-level ``jax.shard_map``; the replication-check kwarg is
-  ``check_vma``.
-* jax 0.4.x/0.5.x: ``jax.experimental.shard_map.shard_map``; the kwarg is
-  ``check_rep``.
+"""``shard_map`` with replication checking off — the one place it is set.
 
 Every shard_map user in the repo (``core.distributed``, ``repro.dist``,
-tests) imports :func:`shard_map_compat` from here instead of re-probing.
-Replication checking is disabled: the solver/curvature collectives
-deliberately produce replicated outputs from sharded inputs (Gram psums,
-factor broadcasts), which the strict checker rejects on some versions.
+tests) imports :func:`shard_map_compat` from here. Replication checking is
+disabled: the solver/curvature collectives deliberately produce replicated
+outputs from sharded inputs (Gram psums, factor broadcasts), which the
+strict checker rejects.
 """
 from __future__ import annotations
 
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map_impl
-    _CHECK_KW = "check_vma"
-except ImportError:  # jax 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _CHECK_KW = "check_rep"
+import jax
 
 __all__ = ["shard_map_compat"]
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``shard_map(f)`` with replication checking disabled, on any
-    supported jax version."""
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **{_CHECK_KW: False})
+    """``jax.shard_map(f)`` with ``check_vma=False``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

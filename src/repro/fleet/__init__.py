@@ -39,6 +39,7 @@ from repro.fleet.dispatcher import (
     ROUTES,
     WorkerHandle,
     launch_fleet,
+    refuse_on_tpu_parent,
 )
 from repro.fleet.gossip import GossipLog, ReplayBuffer
 from repro.fleet.ring import HashRing
@@ -48,5 +49,5 @@ from repro.fleet.worker import FleetWorker
 __all__ = [
     "Channel", "Dispatcher", "FleetWorker", "GossipLog", "HashRing",
     "Message", "ROUTES", "ReplayBuffer", "WireError", "WorkerHandle",
-    "connect", "launch_fleet", "listen",
+    "connect", "launch_fleet", "listen", "refuse_on_tpu_parent",
 ]

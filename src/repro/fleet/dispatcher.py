@@ -72,7 +72,8 @@ from repro.obs import merge as merge_snapshots
 from repro.obs import merge_health
 from repro.serve.server import ServerMetrics, SolveResult
 
-__all__ = ["Dispatcher", "WorkerHandle", "launch_fleet", "ROUTES"]
+__all__ = ["Dispatcher", "WorkerHandle", "launch_fleet", "refuse_on_tpu_parent",
+           "ROUTES"]
 
 ROUTES = ("round_robin", "least_loaded", "by_adapter")
 
@@ -598,6 +599,21 @@ def _repro_pythonpath() -> str:
     return src if not current else f"{src}{os.pathsep}{current}"
 
 
+def refuse_on_tpu_parent(what: str = "launch_fleet") -> None:
+    """Raise when this process holds a TPU backend. A chip belongs to one
+    process: worker subprocesses spawned from here could not open it, and
+    the rendezvous would wait out ``spawn_timeout`` instead of failing."""
+    import jax
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized() and \
+            jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{what}: this process holds the TPU, and a chip belongs to one "
+            "process at a time, so fleet worker subprocesses could not open "
+            "it. Serve from this process instead (no --fleet on a TPU host).")
+
+
 def launch_fleet(n_workers: int, *, init_meta: dict,
                  init_arrays: Optional[dict] = None,
                  route: str = "round_robin", gossip: bool = True,
@@ -611,7 +627,9 @@ def launch_fleet(n_workers: int, *, init_meta: dict,
     ephemeral listener) so there is no port-assignment race. ``init_meta``
     / ``init_arrays`` form the init frame every worker receives — e.g.
     ``{"mode": "inline", "damping": 1e-2}`` with ``{"S0": window}``.
+    Refuses at once in a process that holds a TPU (``refuse_on_tpu_parent``).
     """
+    refuse_on_tpu_parent()
     srv, port = listen()
     srv.settimeout(spawn_timeout)
     env = {**os.environ, "PYTHONPATH": _repro_pythonpath(),

@@ -2,22 +2,27 @@
 
 The paper's namesake op (its "chol" step). n is the *sample* count
 (10²–10⁴), so the whole matrix fits VMEM for n ≤ ~1k fp32 — we factor it in
-a single kernel invocation with a **left-looking panel algorithm**:
+a single kernel invocation with a **right-looking row-panel algorithm** on
+the upper factor U = Lᵀ (rows of U are columns of L, so every dynamic
+access is a row slice — the sublane axis Mosaic indexes natively):
 
   for each panel k of width BP (a ``fori_loop``; the loop body is traced
   once):
-    1. panel correction  P = W[:, k·BP:…] − L·L[k·BP:…, :]ᵀ, with columns
-       ≥ k·BP masked out of L — one (n × n)·(n × BP) MXU matmul;
-    2. in-panel factorization — BP *unrolled* column steps of length-n
-       vector ops (VPU): subtract prior in-panel columns, sqrt the pivot,
-       scale below-diagonal entries, mask above-diagonal to zero.
+    1. load the BP rows of the trailing matrix A (VMEM scratch, A = W
+       minus every earlier panel's contribution);
+    2. in-panel factorization — BP *unrolled* row steps of length-n
+       vector ops (VPU): sqrt the pivot, scale right of the diagonal,
+       zero left of it, and eliminate the row from the panel rows below;
+       single entries are picked with an iota mask and a lane reduction;
+    3. trailing update  A −= U_kᵀ·U_k — one (n × BP)·(BP × n) MXU matmul.
 
 There is no triangular-solve primitive inside Pallas (lax.linalg does not
 lower to Mosaic), which is exactly why the panel step is formulated as
 masked vector arithmetic — the TPU-idiomatic replacement for cuSOLVER's
 ``potrf`` panel TRSM. Cost: n³ MXU FLOPs (vs n³/3 optimal — the trailing
-masked matmul does not exploit symmetry) + O(n²·BP) VPU FLOPs; both are
-negligible next to the O(n²·m) Gram since m ≫ n.
+update does not exploit symmetry) + O(n²·BP) VPU FLOPs; both are
+negligible next to the O(n²·m) Gram since m ≫ n. The kernel writes U;
+the wrapper hands back L = Uᵀ.
 
 Larger n falls back to XLA's cholesky in ``ops.py`` (still n×n — tiny).
 """
@@ -30,46 +35,42 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mosaic import dot_f32, pick_lane
+
 __all__ = ["cholesky_pallas", "MAX_SINGLE_BLOCK_N"]
 
-# W + L + ~2 temporaries in fp32 must fit 16 MB VMEM.
+# W, U, the trailing matrix and one (n, n) matmul result in fp32: the v5e
+# compile asks for ~12.6 MiB of VMEM at n=1024, inside the 16 MiB default
+# scoped limit (tests/test_tpu_compile.py compiles this size).
 MAX_SINGLE_BLOCK_N = 1024
 
 
-def _chol_kernel(w_ref, l_ref, *, panel: int):
-    W = w_ref[...].astype(jnp.float32)
-    n = W.shape[0]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+def _chol_kernel(w_ref, u_ref, a_ref, *, panel: int):
+    n = w_ref.shape[0]
+    a_ref[...] = w_ref[...].astype(jnp.float32)
     cols = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+    prow = jax.lax.broadcasted_iota(jnp.int32, (panel, 1), 0)
 
-    def panel_body(k, L):
-        col0 = k * panel
-        # -- 1. correction from already-factored columns (MXU) --------------
-        Lm = jnp.where(cols < col0, L, 0.0)                     # (n, n)
-        Wp = jax.lax.dynamic_slice(W, (0, col0), (n, panel))    # (n, BP)
-        Lrows = jax.lax.dynamic_slice(Lm, (col0, 0), (panel, n))
-        P = Wp - jax.lax.dot_general(
-            Lm, Lrows, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)                 # (n, BP)
-
-        # -- 2. in-panel left-looking factorization (VPU, unrolled) ---------
-        done = []
+    def panel_body(k, carry):
+        col0 = pl.multiple_of(k * panel, panel)
+        P = a_ref[pl.ds(col0, panel), :]                        # (BP, n)
+        block = jnp.zeros((panel, n), jnp.float32)
         for j in range(panel):
-            c = jax.lax.dynamic_slice(P, (0, j), (n, 1))        # (n, 1)
-            for t, Lt in enumerate(done):
-                ljt = jax.lax.dynamic_slice(Lt, (col0 + j, 0), (1, 1))
-                c = c - Lt * ljt
-            piv = jax.lax.dynamic_slice(c, (col0 + j, 0), (1, 1))
-            d = jnp.sqrt(jnp.maximum(piv, 1e-30))
-            colv = jnp.where(rows > col0 + j, c / d, 0.0)
-            colv = jnp.where(rows == col0 + j, d, colv)
-            done.append(colv)
-        block = jnp.concatenate(done, axis=1)                   # (n, BP)
-        return jax.lax.dynamic_update_slice(L, block, (0, col0))
+            c = jnp.sum(jnp.where(prow == j, P, 0.0), axis=0,
+                        keepdims=True)                          # (1, n)
+            d = jnp.sqrt(jnp.maximum(pick_lane(c, col0 + j), 1e-30))
+            row = jnp.where(cols > col0 + j, c / d, 0.0)
+            row = jnp.where(cols == col0 + j, d, row)
+            block = jnp.where(prow == j, row, block)
+            # P[i] −= U[col0+j, col0+i] · row   for the panel rows i > j
+            coef = jnp.sum(jnp.where(cols == col0 + prow, row, 0.0),
+                           axis=1, keepdims=True)               # (BP, 1)
+            P = P - jnp.where(prow > j, coef, 0.0) * row
+        u_ref[pl.ds(col0, panel), :] = block
+        a_ref[...] -= dot_f32(block, block, ((0,), (0,)))      # (n, n)
+        return carry
 
-    L = jax.lax.fori_loop(0, n // panel, panel_body,
-                          jnp.zeros((n, n), jnp.float32))
-    l_ref[...] = L.astype(l_ref.dtype)
+    jax.lax.fori_loop(0, n // panel, panel_body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("panel", "interpret"))
@@ -78,9 +79,11 @@ def cholesky_pallas(W: jax.Array, *, panel: int = 16,
     """Lower-triangular L with W = L@L.T. W must be SPD, n % panel == 0."""
     n = W.shape[0]
     assert W.shape == (n, n) and n % panel == 0, (W.shape, panel)
-    return pl.pallas_call(
+    U = pl.pallas_call(
         functools.partial(_chol_kernel, panel=panel),
         out_shape=jax.ShapeDtypeStruct((n, n), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],   # trailing A
         interpret=interpret,
         name="blocked_cholesky",
     )(W)
+    return U.T

@@ -17,9 +17,10 @@ HBM in between:
   and x[j] cancels exactly (−b·a + a·b). O(n²·k) VPU FLOPs — negligible
   next to the O(n²·m) Gram it replaces, which is the whole point.
 
-There is no triangular-solve or column-pivot primitive in Mosaic, which is
-why the sweep is value-carried ``dynamic_slice`` arithmetic exactly like
-``_chol_kernel``. Larger n falls back to the jnp reference in ``ops.py``.
+The kernel works on the transposes U = Lᵀ and Xᵀ, so column j of L is row
+j of a VMEM ref (a dynamic sublane slice Mosaic lowers), and the pivots
+a = L[j, j], b = x[j] are picked with an iota mask and a lane reduction.
+Larger n falls back to the jnp reference in ``ops.py``.
 """
 from __future__ import annotations
 
@@ -30,38 +31,36 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.cholesky import MAX_SINGLE_BLOCK_N
+from repro.kernels.mosaic import pick_lane
 
 __all__ = ["cholupdate_pallas", "MAX_SINGLE_BLOCK_N"]
 
 
-def _cholupdate_kernel(l_ref, x_ref, out_ref, *, sign: int, eps: float):
-    L0 = l_ref[...].astype(jnp.float32)
-    X = x_ref[...].astype(jnp.float32)
-    n = L0.shape[0]
-    k = X.shape[1]
+def _cholupdate_kernel(u_ref, xt_ref, out_ref, *, sign: int, eps: float):
+    n = u_ref.shape[0]
+    k = xt_ref.shape[0]
+    out_ref[...] = u_ref[...].astype(jnp.float32)
 
-    def col_sweep(t, L):
-        x = jax.lax.dynamic_slice(X, (0, t), (n, 1))            # (n, 1)
+    def col_sweep(t, carry):
+        x = xt_ref[pl.ds(t, 1), :].astype(jnp.float32)          # (1, n)
 
-        def rot(j, carry):
-            L, x = carry
-            col = jax.lax.dynamic_slice(L, (0, j), (n, 1))
-            a = jax.lax.dynamic_slice(col, (j, 0), (1, 1))
-            b = jax.lax.dynamic_slice(x, (j, 0), (1, 1))
+        def rot(j, x):
+            row = out_ref[pl.ds(j, 1), :]                       # L[:, j]ᵀ
+            a = pick_lane(row, j)
+            b = pick_lane(x, j)
             r = jnp.sqrt(jnp.maximum(a * a + sign * b * b, eps))
-            new_col = (a * col + sign * b * x) / r
-            x_new = (a * x - b * col) / r
-            return jax.lax.dynamic_update_slice(L, new_col, (0, j)), x_new
+            out_ref[pl.ds(j, 1), :] = (a * row + sign * b * x) / r
+            return (a * x - b * row) / r
 
-        L, _ = jax.lax.fori_loop(0, n, rot, (L, x))
-        return L
+        jax.lax.fori_loop(0, n, rot, x)
+        return carry
 
-    L = jax.lax.fori_loop(0, k, col_sweep, L0)
+    jax.lax.fori_loop(0, k, col_sweep, 0)
     # FMA contraction makes the a·b − b·a cancellations inexact at the
-    # 1-ulp level; pin the strict upper triangle back to exactly zero.
+    # 1-ulp level; pin the strict lower triangle of U back to exactly zero.
     rows = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-    out_ref[...] = jnp.where(rows >= cols, L, 0.0).astype(out_ref.dtype)
+    out_ref[...] = jnp.where(rows <= cols, out_ref[...], 0.0)
 
 
 @functools.partial(jax.jit, static_argnames=("sign", "eps", "interpret"))
@@ -74,9 +73,10 @@ def cholupdate_pallas(L: jax.Array, X: jax.Array, *, sign: int = 1,
     n = L.shape[0]
     assert L.shape == (n, n) and X.shape[0] == n, (L.shape, X.shape)
     assert sign in (1, -1), sign
-    return pl.pallas_call(
+    U = pl.pallas_call(
         functools.partial(_cholupdate_kernel, sign=sign, eps=eps),
         out_shape=jax.ShapeDtypeStruct((n, n), jnp.float32),
         interpret=interpret,
         name="rank_k_cholupdate",
-    )(L, X)
+    )(L.T, X.T)
+    return U.T

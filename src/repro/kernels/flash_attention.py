@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 __all__ = ["flash_attention_pallas"]
 
 F32 = jnp.float32
@@ -128,7 +126,7 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=None, scale=None,
             pltpu.VMEM((bq,), F32),
             pltpu.VMEM((bq, hd), F32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="flash_attention_fwd",

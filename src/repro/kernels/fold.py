@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels.mosaic import dot_f32
 
 __all__ = ["fold_cols_pallas"]
 
@@ -40,14 +40,8 @@ def _fold_cols_kernel(s_ref, r_ref, cols_ref, corner_ref):
         corner_ref[...] = jnp.zeros_like(corner_ref)
 
     r = r_ref[...]
-    cols_ref[...] += jax.lax.dot_general(
-        s_ref[...], r, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    corner_ref[...] += jax.lax.dot_general(
-        r, r, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    cols_ref[...] += dot_f32(s_ref[...], r, ((1,), (1,)))
+    corner_ref[...] += dot_f32(r, r, ((1,), (1,)))
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
@@ -73,7 +67,7 @@ def fold_cols_pallas(S: jax.Array, rows: jax.Array, *, bk: int = 512,
             jax.ShapeDtypeStruct((n, k), jnp.float32),
             jax.ShapeDtypeStruct((k, k), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels.mosaic import dot_f32
 
 __all__ = ["gram_pallas", "gram_acc_pallas"]
 
@@ -38,10 +38,7 @@ def _gram_kernel(s_i_ref, s_j_ref, w_ref):
 
     a = s_i_ref[...]
     b = s_j_ref[...]
-    w_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    w_ref[...] += dot_f32(a, b, ((1,), (1,)))
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bk", "interpret"))
@@ -64,7 +61,7 @@ def gram_pallas(S: jax.Array, *, bn: int = 128, bk: int = 512,
         ],
         out_specs=pl.BlockSpec((bn, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, n), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -83,10 +80,7 @@ def _gram_acc_kernel(w_in_ref, s_i_ref, s_j_ref, w_ref):
 
     a = s_i_ref[...]
     b = s_j_ref[...]
-    w_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    w_ref[...] += dot_f32(a, b, ((1,), (1,)))
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bk", "interpret"))
@@ -114,7 +108,7 @@ def gram_acc_pallas(S: jax.Array, W_in: jax.Array, *, bn: int = 128,
         out_specs=pl.BlockSpec((bn, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, n), jnp.float32),
         input_output_aliases={0: 0},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

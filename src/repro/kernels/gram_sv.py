@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels.mosaic import dot_f32
 
 __all__ = ["gram_sv_pallas"]
 
@@ -34,10 +34,7 @@ def _gram_sv_kernel(s_i_ref, s_j_ref, v_ref, w_ref, u_ref):
         w_ref[...] = jnp.zeros_like(w_ref)
 
     a = s_i_ref[...]
-    w_ref[...] += jax.lax.dot_general(
-        a, s_j_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    w_ref[...] += dot_f32(a, s_j_ref[...], ((1,), (1,)))
 
     # u tile (bn, 1): accumulate once per (i, k) — gate on j == 0.
     @pl.when(jnp.logical_and(j == 0, k == 0))
@@ -46,10 +43,7 @@ def _gram_sv_kernel(s_i_ref, s_j_ref, v_ref, w_ref, u_ref):
 
     @pl.when(j == 0)
     def _acc_u():
-        u_ref[...] += jax.lax.dot_general(
-            a, v_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        u_ref[...] += dot_f32(a, v_ref[...], ((1,), (0,)))
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bk", "interpret"))
@@ -78,7 +72,7 @@ def gram_sv_pallas(S: jax.Array, v: jax.Array, *, bn: int = 128,
             jax.ShapeDtypeStruct((n, n), jnp.float32),
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

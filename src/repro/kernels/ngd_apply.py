@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams, SMEM as _SMEM
+from repro.kernels.mosaic import dot_f32
 
 __all__ = ["ngd_apply_pallas"]
 
@@ -28,10 +28,7 @@ __all__ = ["ngd_apply_pallas"]
 def _ngd_apply_kernel(s_ref, w_ref, v_ref, lam_ref, x_ref):
     s = s_ref[...]                      # (n, bk)
     w = w_ref[...]                      # (n, 1)
-    stw = jax.lax.dot_general(          # (bk, 1) — contract the n axis
-        s, w, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    stw = dot_f32(s, w, ((0,), (0,)))   # (bk, 1) — contract the n axis
     inv_lam = 1.0 / lam_ref[0, 0]
     x_ref[...] = ((v_ref[...].astype(jnp.float32) - stw) * inv_lam
                   ).astype(x_ref.dtype)
@@ -54,11 +51,11 @@ def ngd_apply_pallas(S: jax.Array, w: jax.Array, v: jax.Array, lam,
             pl.BlockSpec((n, bk), lambda k: (0, k)),
             pl.BlockSpec((n, 1), lambda k: (0, 0)),
             pl.BlockSpec((bk, 1), lambda k: (k, 0)),
-            pl.BlockSpec((1, 1), lambda k: (0, 0), memory_space=_SMEM),
+            pl.BlockSpec((1, 1), lambda k: (0, 0), memory_space=pltpu.MemorySpace.SMEM),
         ],
         out_specs=pl.BlockSpec((bk, 1), lambda k: (k, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

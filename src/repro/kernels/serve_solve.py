@@ -10,24 +10,31 @@ Dispatched compositionally that is four XLA calls with the m-sized
 intermediates U-producer/apply each re-negotiating HBM.  Here the whole
 identity is ONE kernel invocation with a (2, m/bk) grid:
 
-  phase 0 (cross pass): each (n, bk) tile of S accumulates its S·V
-    contribution into an (n, k) fp32 VMEM scratch that stays resident
+  phase 0 (cross pass): each (n, bk) tile of S accumulates its (S·V)ᵀ
+    contribution into a (k, n) fp32 VMEM scratch that stays resident
     across the whole pass; on the last tile the forward/back triangular
     substitution against the resident L runs *in-kernel* (Mosaic has no
     triangular-solve primitive — it is the same masked row-by-row vector
-    formulation as ``cholesky.py``'s panel step), leaving w in a second
+    formulation as ``cholesky.py``'s panel step), leaving wᵀ in a second
     resident scratch.
   phase 1 (apply pass): S streams through VMEM a second time and each
-    (bk, k) tile of X = (V − Sᵀw)/λ₀ is written exactly once.
+    (k, bk) tile of Xᵀ = (Vᵀ − wᵀS)/λ₀ is written exactly once.
 
-The factor tile, RHS tiles and both (n, k) intermediates are pinned in
-VMEM for the whole microbatch; accumulation is fp32 regardless of the
-window storage dtype (bf16 windows upcast per-tile inside the kernel).
+The kernel runs on Vᵀ and Xᵀ, so the requests lie along sublanes and the
+RHS/solution tiles are lane-dense. XLA already stores a narrow (m, k)
+array lane-dense, as its (k, m) transpose, so the wrapper's transposes
+compile to bitcasts; (bk, k) tiles would instead make XLA copy V and X
+into a row-major layout padded from k to 128 lanes (16× the bytes at
+k=8: 36 GB at whisper-base's m=70.9 M, more than the chip holds). The
+factor tile, RHS tiles and both (k, n) intermediates are pinned in VMEM
+for the whole microbatch; accumulation is fp32 regardless of the window
+storage dtype (bf16 windows upcast per-tile inside the kernel).
 
 ``sv_cross_pallas`` / ``serve_apply_pallas`` are the two S passes as
 standalone kernels — the building blocks the blocked and sharded
 (``repro.dist``, per-slab inside ``shard_map``) serve paths reuse when a
-psum must sit between the cross pass and the substitution.
+psum must sit between the cross pass and the substitution. They take V
+and give X in the same lane-dense Vᵀ/Xᵀ tiles.
 """
 from __future__ import annotations
 
@@ -38,42 +45,44 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams, SMEM as _SMEM
+from repro.kernels.mosaic import dot_f32, pick_lane
 
 __all__ = ["serve_solve_pallas", "sv_cross_pallas", "serve_apply_pallas"]
 
 
-def _trisolve(L, U):
-    """w = L⁻ᵀ L⁻¹ U by masked row-by-row substitution (no lax.linalg in
-    Mosaic). L: (n, n) fp32 lower-triangular; U: (n, k) fp32. O(n²k) VPU/MXU
-    work in 2n sequential steps — negligible next to the O(n·m·k) passes."""
-    n, k = U.shape
+def _trisolve(l_ref, Ut):
+    """wᵀ for w = L⁻ᵀ L⁻¹ U by row-by-row substitution (no lax.linalg in
+    Mosaic). l_ref: (n, n) lower-triangular factor in VMEM; Ut: (k, n) fp32,
+    the right-hand sides laid out along lanes. Both sweeps read only row i
+    of L (a dynamic sublane slice); entries at lane i are picked and set with
+    an iota mask. O(n²k) VPU work in 2n sequential steps — negligible next
+    to the O(n·m·k) passes."""
+    k, n = Ut.shape
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
 
     def fwd(i, Y):
-        # rows ≥ i of Y are still zero, so the full-row product only picks
+        # lanes ≥ i of Y are still zero, so the full-row product only picks
         # up already-solved entries
-        li = jax.lax.dynamic_slice(L, (i, 0), (1, n))             # (1, n)
-        acc = jax.lax.dot_general(li, Y, (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        ui = jax.lax.dynamic_slice(U, (i, 0), (1, k))
-        dii = jax.lax.dynamic_slice(L, (i, i), (1, 1))
-        return jax.lax.dynamic_update_slice(Y, (ui - acc) / dii, (i, 0))
+        li = l_ref[pl.ds(i, 1), :].astype(jnp.float32)            # (1, n)
+        acc = jnp.sum(Y * li, axis=1, keepdims=True)              # (k, 1)
+        yi = (pick_lane(Ut, i) - acc) / pick_lane(li, i)
+        return jnp.where(cols == i, yi, Y)
 
-    Y = jax.lax.fori_loop(0, n, fwd, jnp.zeros_like(U))
+    Y = jax.lax.fori_loop(0, n, fwd, jnp.zeros_like(Ut))
 
-    def bwd(t, Wv):
+    def bwd(t, carry):
+        # right-looking: once w_i is known, y_r −= L[i, r]·w_i for r < i
+        Y, Wt = carry
         i = n - 1 - t
-        ci = jax.lax.dynamic_slice(L, (0, i), (n, 1))             # col i
-        acc = jax.lax.dot_general(ci, Wv, (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        yi = jax.lax.dynamic_slice(Y, (i, 0), (1, k))
-        dii = jax.lax.dynamic_slice(L, (i, i), (1, 1))
-        return jax.lax.dynamic_update_slice(Wv, (yi - acc) / dii, (i, 0))
+        li = l_ref[pl.ds(i, 1), :].astype(jnp.float32)
+        wi = pick_lane(Y, i) / pick_lane(li, i)
+        return Y - wi * li, jnp.where(cols == i, wi, Wt)
 
-    return jax.lax.fori_loop(0, n, bwd, jnp.zeros_like(U))
+    _, Wt = jax.lax.fori_loop(0, n, bwd, (Y, jnp.zeros_like(Ut)))
+    return Wt
 
 
-def _serve_solve_kernel(s_ref, l_ref, v_ref, lam_ref, x_ref, u_ref, w_ref):
+def _serve_solve_kernel(s_ref, l_ref, vt_ref, lam_ref, xt_ref, ut_ref, wt_ref):
     p = pl.program_id(0)
     j = pl.program_id(1)
     nj = pl.num_programs(1)
@@ -82,78 +91,73 @@ def _serve_solve_kernel(s_ref, l_ref, v_ref, lam_ref, x_ref, u_ref, w_ref):
     def _cross():
         @pl.when(j == 0)
         def _init():
-            u_ref[...] = jnp.zeros_like(u_ref)
+            ut_ref[...] = jnp.zeros_like(ut_ref)
 
-        u_ref[...] += jax.lax.dot_general(
-            s_ref[...], v_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        ut_ref[...] += dot_f32(vt_ref[...], s_ref[...],   # (k, n): contract bk
+                               ((1,), (1,)))
 
         @pl.when(j == nj - 1)
         def _solve():
-            w_ref[...] = _trisolve(l_ref[...].astype(jnp.float32), u_ref[...])
+            wt_ref[...] = _trisolve(l_ref, ut_ref[...])
 
     @pl.when(p == 1)
     def _apply():
-        stw = jax.lax.dot_general(                       # (bk, k): contract n
-            s_ref[...], w_ref[...], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        wts = dot_f32(wt_ref[...], s_ref[...], ((1,), (0,)))  # (k, bk)
         inv_lam = 1.0 / lam_ref[0, 0]
-        x_ref[...] = (v_ref[...].astype(jnp.float32) - stw) * inv_lam
+        xt_ref[...] = (vt_ref[...] - wts) * inv_lam
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
 def serve_solve_pallas(S: jax.Array, L: jax.Array, V: jax.Array, lam,
                        *, bk: int = 512, interpret: bool = False) -> jax.Array:
     """X = (V − Sᵀ L⁻ᵀL⁻¹ S V)/λ.  S: (n, m); L: (n, n); V: (m, k) fp32.
-    Returns (m, k) fp32. m % bk == 0 (zero pad is exact)."""
+    Returns (m, k) fp32. m % bk == 0 (zero pad is exact). The kernel runs
+    on Vᵀ/Xᵀ, so its (k, bk) tiles are lane-dense."""
     n, m = S.shape
     k = V.shape[1]
     assert m % bk == 0, (m, bk)
     lam2 = jnp.asarray(lam, jnp.float32).reshape(1, 1)
 
-    return pl.pallas_call(
+    Xt = pl.pallas_call(
         _serve_solve_kernel,
         grid=(2, m // bk),
         in_specs=[
             pl.BlockSpec((n, bk), lambda p, j: (0, j)),
             pl.BlockSpec((n, n), lambda p, j: (0, 0)),
-            pl.BlockSpec((bk, k), lambda p, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda p, j: (0, 0), memory_space=_SMEM),
+            pl.BlockSpec((k, bk), lambda p, j: (0, j)),
+            pl.BlockSpec((1, 1), lambda p, j: (0, 0),
+                         memory_space=pltpu.MemorySpace.SMEM),
         ],
-        out_specs=pl.BlockSpec((bk, k), lambda p, j: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, k), jnp.float32),
+        out_specs=pl.BlockSpec((k, bk), lambda p, j: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((k, m), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((n, k), jnp.float32),     # resident U = S·V
-            pltpu.VMEM((n, k), jnp.float32),     # resident w = L⁻ᵀL⁻¹U
+            pltpu.VMEM((k, n), jnp.float32),     # resident Uᵀ = (S·V)ᵀ
+            pltpu.VMEM((k, n), jnp.float32),     # resident wᵀ = (L⁻ᵀL⁻¹U)ᵀ
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
         name="serve_solve_fused",
-    )(S, L, V.astype(jnp.float32), lam2)
+    )(S, L, V.astype(jnp.float32).T, lam2)
+    return Xt.T
 
 
-def _sv_cross_kernel(s_ref, v_ref, u_ref):
+def _sv_cross_kernel(s_ref, vt_ref, u_ref):
     j = pl.program_id(0)
 
     @pl.when(j == 0)
     def _init():
         u_ref[...] = jnp.zeros_like(u_ref)
 
-    u_ref[...] += jax.lax.dot_general(
-        s_ref[...], v_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    u_ref[...] += dot_f32(s_ref[...], vt_ref[...], ((1,), (1,)))
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
 def sv_cross_pallas(S: jax.Array, V: jax.Array, *, bk: int = 512,
                     interpret: bool = False) -> jax.Array:
     """U = S @ V, fp32 accumulation into a single resident (n, k) tile.
-    S: (n, m); V: (m, k). m % bk == 0."""
+    S: (n, m); V: (m, k), read as (k, bk) tiles of Vᵀ. m % bk == 0."""
     n, m = S.shape
     k = V.shape[1]
     assert m % bk == 0, (m, bk)
@@ -162,50 +166,49 @@ def sv_cross_pallas(S: jax.Array, V: jax.Array, *, bk: int = 512,
         grid=(m // bk,),
         in_specs=[
             pl.BlockSpec((n, bk), lambda j: (0, j)),
-            pl.BlockSpec((bk, k), lambda j: (j, 0)),
+            pl.BlockSpec((k, bk), lambda j: (0, j)),
         ],
         out_specs=pl.BlockSpec((n, k), lambda j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, k), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
         name="serve_sv_cross",
-    )(S, V.astype(jnp.float32))
+    )(S, V.astype(jnp.float32).T)
 
 
-def _serve_apply_kernel(s_ref, w_ref, v_ref, lam_ref, x_ref):
-    stw = jax.lax.dot_general(
-        s_ref[...], w_ref[...], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+def _serve_apply_kernel(s_ref, wt_ref, vt_ref, lam_ref, xt_ref):
+    wts = dot_f32(wt_ref[...], s_ref[...], ((1,), (0,)))      # (k, bk)
     inv_lam = 1.0 / lam_ref[0, 0]
-    x_ref[...] = (v_ref[...].astype(jnp.float32) - stw) * inv_lam
+    xt_ref[...] = (vt_ref[...] - wts) * inv_lam
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
 def serve_apply_pallas(S: jax.Array, w: jax.Array, V: jax.Array, lam,
                        *, bk: int = 512, interpret: bool = False) -> jax.Array:
     """X = (V − Sᵀ @ w) / λ — the multi-RHS apply pass. S: (n, m);
-    w: (n, k); V: (m, k). Returns (m, k) fp32. m % bk == 0."""
+    w: (n, k); V: (m, k). Returns (m, k) fp32. m % bk == 0. The kernel
+    runs on Vᵀ/Xᵀ, as ``serve_solve_pallas`` does."""
     n, m = S.shape
     k = V.shape[1]
     assert m % bk == 0, (m, bk)
     lam2 = jnp.asarray(lam, jnp.float32).reshape(1, 1)
-    return pl.pallas_call(
+    Xt = pl.pallas_call(
         _serve_apply_kernel,
         grid=(m // bk,),
         in_specs=[
             pl.BlockSpec((n, bk), lambda j: (0, j)),
-            pl.BlockSpec((n, k), lambda j: (0, 0)),
-            pl.BlockSpec((bk, k), lambda j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda j: (0, 0), memory_space=_SMEM),
+            pl.BlockSpec((k, n), lambda j: (0, 0)),
+            pl.BlockSpec((k, bk), lambda j: (0, j)),
+            pl.BlockSpec((1, 1), lambda j: (0, 0), memory_space=pltpu.MemorySpace.SMEM),
         ],
-        out_specs=pl.BlockSpec((bk, k), lambda j: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, k), jnp.float32),
-        compiler_params=_CompilerParams(
+        out_specs=pl.BlockSpec((k, bk), lambda j: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((k, m), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
         name="serve_apply",
-    )(S, w.astype(jnp.float32), V.astype(jnp.float32), lam2)
+    )(S, w.astype(jnp.float32).T, V.astype(jnp.float32).T, lam2)
+    return Xt.T

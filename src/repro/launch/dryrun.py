@@ -1,15 +1,12 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# persistent compile cache: hillclimb iterations re-lower unchanged cells
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/root/.cache/jax_dryrun")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "2")
 
 """Multi-pod dry-run: prove every (arch × shape × mesh) cell lowers,
 partitions, and compiles on the production meshes, and extract the roofline
 inputs (memory analysis, FLOPs/bytes, collective traffic) from the compiled
 artifact.
 
-The two lines above MUST stay first — jax locks the device count at first
+The line above MUST stay first — jax locks the device count at first
 initialization, and the dry-run needs 512 placeholder host devices to build
 the (2, 16, 16) production mesh. Nothing here allocates: all model state is
 ShapeDtypeStruct.
@@ -36,6 +33,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import configs
 from repro.configs.shapes import SHAPES, applicable
 from repro.launch import hlo_analysis
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import MODEL, make_production_mesh
 from repro.launch.shardings import param_shardings, tree_size
 from repro.models.api import get_api, make_input_specs
@@ -214,25 +212,15 @@ def run_cell(arch, shape_name, mesh_kind, optimizer="adamw",
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     # explicit mesh context: lets opt-in perf levers use bare-PartitionSpec
     # with_sharding_constraint (jax resolves axis names against this mesh).
-    # jax ≥ 0.5 has jax.sharding.set_mesh; 0.4.x uses the Mesh context
-    # manager for the same purpose.
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        set_mesh(mesh)
-        import contextlib
-        mesh_ctx = contextlib.nullcontext()
+    jax.sharding.set_mesh(mesh)
+    if solver_nm:
+        lowered, meta = build_solver_lowered(*solver_nm, mesh)
     else:
-        mesh_ctx = mesh
-    with mesh_ctx:
-        if solver_nm:
-            lowered, meta = build_solver_lowered(*solver_nm, mesh)
-        else:
-            lowered, meta = build_lowered(arch, shape_name, mesh,
-                                          optimizer=optimizer,
-                                          overrides=overrides,
-                                          ngd_opts=ngd_opts,
-                                          variant=variant)
-        rec = compile_and_analyze(lowered, meta, mesh)
+        lowered, meta = build_lowered(arch, shape_name, mesh,
+                                      optimizer=optimizer,
+                                      overrides=overrides,
+                                      ngd_opts=ngd_opts, variant=variant)
+    rec = compile_and_analyze(lowered, meta, mesh)
     rec["mesh"] = mesh_kind
     rec["variant"] = variant
     if overrides:
@@ -275,6 +263,7 @@ def main(argv=None):
                     default="baseline",
                     help="tuned = CONFIG + confirmed §Perf levers + donation")
     args = ap.parse_args(argv)
+    use_compile_cache()
     out = pathlib.Path(args.out)
     out.mkdir(parents=True, exist_ok=True)
 

@@ -15,15 +15,9 @@ POD, DATA, MODEL = "pod", "data", "model"
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across versions: ``axis_types`` only exists (and is
-    needed — Auto is not the default) on jax ≥ 0.5; 0.4.x meshes are Auto
-    implicitly and the kwarg is absent."""
-    try:
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (TypeError, AttributeError):
-        return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with every axis Auto (not the default)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -23,6 +23,7 @@ from repro import configs
 from repro.checkpoint import checkpoint as ckpt
 from repro.data import SyntheticLM, place
 from repro.launch import train as T
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.launch.supervisor import SupervisorConfig, run_supervised
 from repro.models.api import get_api
@@ -424,6 +425,7 @@ def train_main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--inject-failure-at", type=int, default=None)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke \
         else configs.get_config(args.arch)

@@ -1,9 +1,11 @@
 """Optional ``jax.profiler`` capture hooks around the coalesced solve.
 
 Kernel-level drill-down for when the span tracer says "device solve"
-is the slow stage but not *why*.  Everything degrades to a no-op when
-profiling is off or the profiler is unavailable, so the serving hot
-path carries a single ``if`` when disabled.
+is the slow stage but not *why*.  Every method is a no-op when profiling
+is off, so the serving hot path carries a single ``if`` when disabled.
+When a trace directory was asked for, a failed ``start_trace`` or
+``stop_trace`` raises: a run that was meant to be profiled does not go
+on without its trace.
 """
 
 from __future__ import annotations
@@ -29,33 +31,24 @@ class ProfileHooks:
     def start(self) -> None:
         if self.log_dir is None or self._active:
             return
-        try:
-            import jax
+        import jax
 
-            jax.profiler.start_trace(self.log_dir)
-            self._active = True
-        except Exception:  # profiler backend unavailable: stay a no-op
-            self.log_dir = None
+        jax.profiler.start_trace(self.log_dir)
+        self._active = True
 
     def stop(self) -> None:
         if not self._active:
             return
-        try:
-            import jax
+        import jax
 
-            jax.profiler.stop_trace()
-        except Exception:
-            pass
         self._active = False
+        jax.profiler.stop_trace()
 
     def step(self, name: str = "coalesced_solve", step: int | None = None):
         """Context manager annotating one solve; no-op when inactive."""
         if not self._active:
             return contextlib.nullcontext()
-        try:
-            import jax
+        import jax
 
-            kwargs = {} if step is None else {"step_num": step}
-            return jax.profiler.StepTraceAnnotation(name, **kwargs)
-        except Exception:
-            return contextlib.nullcontext()
+        kwargs = {} if step is None else {"step_num": step}
+        return jax.profiler.StepTraceAnnotation(name, **kwargs)

@@ -39,6 +39,7 @@ import numpy as np
 from repro import configs
 from repro.checkpoint import checkpoint as ckpt
 from repro.core.damping import DampingState, LevenbergMarquardtDamping
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.launch.trainer import build_server
 
@@ -153,6 +154,7 @@ def serve_main(argv=None):
                          "DIR/worker<i>/)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke \
         else configs.get_config(args.arch)

@@ -46,7 +46,7 @@ from jax.scipy.linalg import solve_triangular
 
 from repro.core.operator import is_blocked
 from repro.core.solvers import CholFactorization
-from repro.curvature.update import chol_downdate, chol_update
+from repro.kernels.ops import cholupdate
 from repro.serve.state import ServeState, as_factorization, serve_mode
 
 __all__ = ["TenantDelta", "init_tenant_delta", "project_rows", "delta_fold",
@@ -197,21 +197,22 @@ def delta_correction(delta: TenantDelta, lam, *, return_cond: bool = False):
 
 
 def delta_factor(delta: TenantDelta, L: jax.Array, lam, *,
-                 method: str = "composed", return_cond: bool = False):
+                 return_cond: bool = False):
     """The tenant's resident-λ factor from the base factor: O(n²·r).
 
     ``L`` must be the base chol(W + λĨ) at the same ``lam``; hot tenants
     cache the result (``TenantManager``), cold tenants recompute on
-    demand. An empty delta returns a factor equal to L.
-    ``return_cond=True``: also return the live core conditioning (see
-    ``delta_correction``) as ``(L_t, cond)``."""
+    demand. An empty delta returns a factor equal to L. Both rank-r
+    sweeps go through ``repro.kernels.ops.cholupdate``: the in-VMEM
+    Pallas kernel on a TPU, the ``repro.curvature.update`` reference
+    elsewhere. ``return_cond=True``: also return the live core
+    conditioning (see ``delta_correction``) as ``(L_t, cond)``."""
     if return_cond:
         up, down, cond = delta_correction(delta, lam, return_cond=True)
-        return chol_downdate(chol_update(L, up, method=method), down,
-                             method=method), cond
-    up, down = delta_correction(delta, lam)
-    return chol_downdate(chol_update(L, up, method=method), down,
-                         method=method)
+    else:
+        up, down = delta_correction(delta, lam)
+    L_t = cholupdate(cholupdate(L, up, sign=+1), down, sign=-1)
+    return (L_t, cond) if return_cond else L_t
 
 
 def tenant_factorization(state: ServeState, delta: TenantDelta, *,

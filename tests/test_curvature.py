@@ -1,12 +1,12 @@
 """Streaming curvature subsystem: rank-k update/downdate equivalence
 (real/complex × dense/blocked), window algebra, streaming Gram
 accumulation, and the cross-step cache policy (including inside NGD)."""
+import hypothesis
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-from _hypothesis_compat import hypothesis, st
+from hypothesis import strategies as st
 
 from repro.core import (
     BlockedScores,

@@ -45,8 +45,7 @@ def test_sharded_solvers_match_local():
 def test_sharded_blocked_solve_matches_local():
     """Per-layer BlockedScores under shard_map: every block column-sharded
     over the model axis, one n² psum total. Results are consumed per block
-    (the optimizer's access pattern) — cross-block jnp.concatenate of
-    shard_map outputs mis-reshards on some jaxlib 0.4 CPU builds."""
+    (the optimizer's access pattern)."""
     run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import (BlockedScores, chol_solve,
@@ -70,13 +69,10 @@ def test_sharded_blocked_solve_matches_local():
 
 
 def test_sharded_blocked_per_block_consumption_regression():
-    """Regression for the documented jaxlib-0.4 caveat: the blocked shard_map
-    solver's outputs must be consumed *per block* — each block gathered or
-    reduced on its own — and stay correct that way. (Cross-block
-    ``jnp.concatenate`` of shard_map outputs mis-reshards on some jaxlib
-    0.4 CPU builds: replication over the unmentioned data axis turns into a
-    sum. This test pins the supported access pattern so the workaround in
-    ``sharded_blocked_chol_solve``'s docstring can't silently rot.)"""
+    """The blocked shard_map solver's outputs are consumed *per block* —
+    each block gathered or reduced on its own — and stay correct that way.
+    This pins the access pattern ``sharded_blocked_chol_solve``'s docstring
+    prescribes."""
     run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import BlockedScores, chol_solve, sharded_blocked_chol_solve

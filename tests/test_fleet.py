@@ -525,6 +525,31 @@ def test_subprocess_fleet_mixed_trace_reconciles(tmp_path):
         disp.shutdown(timeout=60)
 
 
+def test_launch_fleet_refuses_at_once_on_a_tpu_parent(monkeypatch):
+    """A process that holds the TPU must not spawn fleet workers (they
+    could not open the chip): launch_fleet raises before it listens or
+    spawns anything, instead of waiting out spawn_timeout."""
+    import subprocess
+    import time
+
+    import jax
+
+    from repro.fleet import dispatcher
+
+    jax.devices()                          # this process holds its backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def no_spawn(*a, **k):
+        raise AssertionError("spawned a worker")
+
+    monkeypatch.setattr(subprocess, "Popen", no_spawn)
+    monkeypatch.setattr(dispatcher, "listen", no_spawn)
+    t0 = time.monotonic()
+    with pytest.raises(RuntimeError, match="holds the TPU"):
+        launch_fleet(2, init_meta={"mode": "inline"}, spawn_timeout=300.0)
+    assert time.monotonic() - t0 < 5.0
+
+
 @pytest.mark.slow
 def test_subprocess_fleet_by_adapter_partitions_exactly():
     """Gossip off + by_adapter: each worker's responses are bit-identical

@@ -1,12 +1,13 @@
 """Layer-level model tests: attention vs naive reference (hypothesis
 sweeps), chunked SSD vs exact recurrence, MoE dispatch invariants, ring
 cache equivalence."""
+import hypothesis
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import strategies as st
 
-from _hypothesis_compat import hypothesis, st
 from repro.models import layers as L
 from repro.models.config import BlockSlot, ModelConfig
 

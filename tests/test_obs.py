@@ -13,6 +13,7 @@ import pytest
 
 from repro.obs import (
     MetricsRegistry,
+    ProfileHooks,
     Tracer,
     default_buckets,
     merge,
@@ -317,3 +318,24 @@ def test_tenant_manager_emits_occupancy_series():
     assert snap["counters"]["tenants.activations"] == 1
     assert snap["gauges"]["tenants.spilled"] == 0
     assert snap["histograms"]["tenants.activate_latency_s"]["count"] == 1
+
+
+@pytest.mark.parametrize("phase", ["start_trace", "stop_trace"])
+def test_profile_hooks_raise_when_the_trace_fails(monkeypatch, tmp_path,
+                                                  phase):
+    """With a trace directory asked for, a failed profiler call raises
+    rather than leaving the run unprofiled in silence."""
+    import jax
+
+    def fail(*a, **k):
+        raise RuntimeError(f"{phase} refused")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    monkeypatch.setattr(jax.profiler, phase, fail)
+    hooks = ProfileHooks(str(tmp_path / "prof"))
+    with pytest.raises(RuntimeError, match=f"{phase} refused"):
+        hooks.start()
+        hooks.stop()
+    assert not hooks._active
+    ProfileHooks(None).start()       # no directory: still a no-op

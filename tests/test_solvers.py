@@ -1,11 +1,12 @@
 """Solver correctness: every method vs the direct O(m³) oracle, the paper's
 SR variants, and property-based invariants (hypothesis)."""
+import hypothesis
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import strategies as st
 
-from _hypothesis_compat import hypothesis, st
 from repro.core import (
     SOLVERS,
     ConstantDamping,

@@ -150,3 +150,23 @@ def test_straggler_watchdog():
     w.observe(16, 0.5)       # 50× median
     w.observe(17, 0.011)
     assert w.straggler_steps == [16]
+
+
+def test_compile_cache_honours_env_else_fixed_checkout_dir(monkeypatch):
+    """The one compile-cache setting: JAX_COMPILATION_CACHE_DIR wins and
+    nothing is set in code; otherwise a fixed, git-ignored directory of
+    the checkout (the same path on every call and in every process)."""
+    from repro.launch import compile_cache
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    set_dirs = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: set_dirs.append((name, value)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.use_compile_cache() == "/elsewhere/cache"
+    assert set_dirs == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    first = compile_cache.use_compile_cache()
+    assert first == compile_cache.use_compile_cache() \
+        == str(root / "artifacts" / "jax_cache")
+    assert set_dirs == [("jax_compilation_cache_dir", first)] * 2
