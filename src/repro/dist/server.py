@@ -39,7 +39,6 @@ from __future__ import annotations
 import functools
 import threading
 import time
-from contextlib import nullcontext as _nullcontext
 from typing import Any, Dict, List, Optional, Set, Tuple
 
 import jax
@@ -51,6 +50,7 @@ from repro.core.operator import BlockedScores
 from repro.core.shard_compat import shard_map_compat
 from repro.dist.state import DistSpec, ShardedServeState
 from repro.kernels import ops as kernel_ops
+from repro.obs.trace import host_sync, span
 from repro.serve.batcher import Microbatch, TokenBudgetBatcher
 from repro.serve.server import ServerMetrics, SolveResult, \
     _coalesced_solve, _rows_k
@@ -185,10 +185,19 @@ class AsyncSolveServer:
         no ``dist`` bound yet, the state's spec is bound automatically so
         folds and refreshes run through the sharded cholupdate.
       clock: latency timestamps (injectable for tests).
-      registry / tracer / profile / health: as on ``SolveServer`` — the
+      registry / tracer / health: as on ``SolveServer`` — the
         async server additionally splits queue wait at the *dispatch* boundary
         (submit → dispatch vs dispatch → materialized), which is where
         the pipelining happens.
+
+    Spans (``repro.obs.span``, always on, on the profiler's clock):
+    ``serve.submit`` with its ``serve.submit.lock`` on the caller's
+    thread; on the worker ``serve.idle`` while nothing is queued, and per
+    microbatch one ``serve.microbatch`` step span holding ``serve.form``,
+    ``serve.dispatch``, the adaptation's ``adapt.*``, ``serve.wait``,
+    ``serve.results`` and ``serve.release``. Each blocking device-to-host
+    read on the worker is a ``host.sync`` span and one more on the
+    registry's ``serve.host_syncs``.
 
     The worker thread starts immediately; use as a context manager or
     call ``shutdown()`` when done.
@@ -199,7 +208,7 @@ class AsyncSolveServer:
                  adaptation=None, policy: str = "cached",
                  monitor_drift: bool = True, jitter: float = 0.0,
                  tenants=None, clock=time.perf_counter,
-                 registry=None, tracer=None, profile=None, health=None,
+                 registry=None, tracer=None, health=None,
                  recorder=None, metrics_window: int = 4096):
         if policy not in ("cached", "refactorize"):
             raise ValueError(f"policy must be 'cached' or 'refactorize', "
@@ -238,7 +247,6 @@ class AsyncSolveServer:
         self.clock = clock
         self.registry = registry
         self.tracer = tracer
-        self.profile = profile
         self.metrics = ServerMetrics(window=metrics_window,
                                      registry=registry, prefix="serve")
         if registry is not None and tenants is not None \
@@ -255,6 +263,9 @@ class AsyncSolveServer:
         if health is not None and self.adaptation is not None \
                 and getattr(self.adaptation, "health", None) is None:
             self.adaptation.health = health
+        if tracer is not None and self.adaptation is not None \
+                and getattr(self.adaptation, "tracer", None) is None:
+            self.adaptation.tracer = tracer
         # optional FlightRecorder: request digests land at the response
         # boundary (_finalize — the worker's only block_until_ready) and
         # the recorder observes at the maintenance boundary, mirroring
@@ -288,22 +299,29 @@ class AsyncSolveServer:
         if tenant is not None and self.tenants is None:
             raise RuntimeError("tenant= requires a TenantManager "
                                "(AsyncSolveServer(tenants=...))")
-        lam = float(self.state.lam0) if damping is None else float(damping)
-        with self._cv:
-            self._raise_if_failed()
-            if self._stopping:
-                raise RuntimeError("server is shut down")
-            req = self.batcher.submit(v, damping=lam, tokens=tokens,
-                                      rows=rows, payload=payload,
-                                      tenant=tenant, trace=trace)
-            req.t_submit = self.clock()
-            if self.registry is not None:
-                qs = self.batcher.queue_stats(req.t_submit)
-                self.registry.gauge("serve.queue_depth").set(qs["depth"])
-                self.registry.gauge("serve.queue_oldest_age_s").set(
-                    qs["oldest_age_s"])
-            self._pending.add(req.uid)
-            self._cv.notify_all()
+        with span("serve.submit", tracer=self.tracer, trace=trace) as sp:
+            lam = float(self.state.lam0) if damping is None \
+                else float(damping)
+            with span("serve.submit.lock", tracer=self.tracer):
+                self._cv.acquire()
+            try:
+                self._raise_if_failed()
+                if self._stopping:
+                    raise RuntimeError("server is shut down")
+                req = self.batcher.submit(v, damping=lam, tokens=tokens,
+                                          rows=rows, payload=payload,
+                                          tenant=tenant, trace=trace)
+                req.t_submit = self.clock()
+                if self.registry is not None:
+                    qs = self.batcher.queue_stats(req.t_submit)
+                    self.registry.gauge("serve.queue_depth").set(qs["depth"])
+                    self.registry.gauge("serve.queue_oldest_age_s").set(
+                        qs["oldest_age_s"])
+                self._pending.add(req.uid)
+                self._cv.notify_all()
+            finally:
+                self._cv.release()
+            sp.set(uid=req.uid)
         return req.uid
 
     def result(self, uid: int, *, timeout: Optional[float] = None
@@ -461,17 +479,16 @@ class AsyncSolveServer:
     def _run(self) -> None:
         try:
             inflight: Optional[Tuple[Microbatch, tuple]] = None
+            n_mb = 0
             while True:
-                mb = None
                 with self._cv:
-                    while (len(self.batcher) == 0 and not self._stopping
-                           and inflight is None and not self._maintenance):
-                        self._cv.wait()
+                    if self._nothing_to_do(inflight):
+                        with span("serve.idle", tracer=self.tracer):
+                            while self._nothing_to_do(inflight):
+                                self._cv.wait()
                     maint = self._maintenance[:]
-                    if len(self.batcher):
-                        mb = self.batcher.next_microbatch()
-                    stop_now = (self._stopping and len(self.batcher) == 0
-                                and not maint)
+                    queued = len(self.batcher) > 0
+                    stop_now = (self._stopping and not queued and not maint)
                 if maint:
                     # gossiped folds apply in order, between microbatches
                     # — same boundary as request-carried rows; the next
@@ -483,34 +500,18 @@ class AsyncSolveServer:
                     with self._cv:
                         del self._maintenance[:len(maint)]
                         self._cv.notify_all()
-                if mb is not None:
-                    handle = self._dispatch(mb)
-                    if mb.tenant is not None:
-                        # tenant-private folds: into the delta, right after
-                        # the same-microbatch solve dispatched (the eager
-                        # solve → fold ordering, per tenant)
-                        for req in mb.requests:
-                            if req.rows is not None:
-                                self.tenants.fold(self.state, mb.tenant,
-                                                  req.rows)
-                    if self.adaptation is not None:
-                        # the fold reads state, never the solve's outputs:
-                        # dispatching it before materializing responses
-                        # keeps the device stream contiguous while
-                        # preserving the eager solve → fold → refresh
-                        # value ordering. Results release only once the
-                        # refresh decision is in, so flush() doubles as a
-                        # state-snapshot barrier.
-                        self._adapt_folds(mb)
-                        results = self._finalize(mb, handle)
-                        self._maybe_refresh()
-                        self._release(results)
-                    elif inflight is not None:
-                        nxt = (mb, handle)
-                        self._release(self._finalize(*inflight))
-                        inflight = nxt              # i+1 runs while i lands
-                    else:
-                        inflight = (mb, handle)
+                if queued:
+                    n_mb += 1
+                    with span("serve.microbatch", tracer=self.tracer,
+                              step_num=n_mb) as step:
+                        with self._cv, span("serve.form",
+                                            tracer=self.tracer) as form:
+                            mb = self.batcher.next_microbatch()
+                            if mb is not None:
+                                form.set(k=mb.k)
+                        if mb is not None:      # None: cancelled meanwhile
+                            step.set(k=mb.k)
+                            inflight = self._serve_microbatch(mb, inflight)
                 elif inflight is not None:
                     self._release(self._finalize(*inflight))
                     inflight = None
@@ -521,22 +522,56 @@ class AsyncSolveServer:
                 self._error = e
                 self._cv.notify_all()
 
+    def _nothing_to_do(self, inflight) -> bool:
+        return (len(self.batcher) == 0 and not self._stopping
+                and inflight is None and not self._maintenance)
+
+    def _serve_microbatch(self, mb: Microbatch, inflight):
+        """Dispatch ``mb``; returns the microbatch left in flight."""
+        handle = self._dispatch(mb)
+        if mb.tenant is not None:
+            # tenant-private folds: into the delta, right after the
+            # same-microbatch solve dispatched (the eager solve → fold
+            # ordering, per tenant)
+            for req in mb.requests:
+                if req.rows is not None:
+                    self.tenants.fold(self.state, mb.tenant, req.rows)
+        if self.adaptation is not None:
+            # the fold reads state, never the solve's outputs: dispatching
+            # it before materializing responses keeps the device stream
+            # contiguous while preserving the eager solve → fold → refresh
+            # value ordering. Results release only once the refresh
+            # decision is in, so flush() doubles as a state-snapshot
+            # barrier.
+            self._adapt_folds(mb)
+            results = self._finalize(mb, handle)
+            self._maybe_refresh()
+            self._release(results)
+            return inflight
+        if inflight is not None:
+            self._release(self._finalize(*inflight))
+        return mb, handle                    # i+1 runs while i lands
+
+    def _sync(self, site: str):
+        return host_sync(site, registry=self.registry, tracer=self.tracer)
+
     def _dispatch(self, mb: Microbatch) -> tuple:
         """Launch the coalesced solve; returns unmaterialized arrays plus
         the dispatch timestamp (the queue-wait / device-solve split)."""
         t_disp = self.clock()
-        step_ctx = self.profile.step(step=self.metrics.served) \
-            if self.profile is not None else _nullcontext()
-        with step_ctx:
-            x, resid = self._dispatch_arrays(mb)
+        with span("serve.dispatch", tracer=self.tracer, k=mb.k,
+                  uids=[r.uid for r in mb.requests]) as sp:
+            x, resid = self._dispatch_arrays(mb, sp)
         return x, resid, t_disp
 
-    def _dispatch_arrays(self, mb: Microbatch) -> tuple:
+    def _dispatch_arrays(self, mb: Microbatch, sp) -> tuple:
         st = self.state
         if mb.tenant is not None:
             return self._dispatch_tenant(mb)
-        lam0 = float(st.lam0)
+        with self._sync("lam0"):
+            lam0 = float(st.lam0)
         uniform = all(r.damping == lam0 for r in mb.requests)
+        sp.set(uniform=uniform)
         monitor = self.monitor_drift and self.policy == "cached"
         refactorize = self.policy == "refactorize"
         if self.spec is None:
@@ -624,8 +659,15 @@ class AsyncSolveServer:
         """The response boundary: the only block_until_ready."""
         x, resid, t_disp = handle
         x = self._unpad_x(x)
-        jax.block_until_ready(x)
+        with span("serve.wait", tracer=self.tracer):
+            jax.block_until_ready(x)
         t_done = self.clock()
+        with span("serve.results", tracer=self.tracer):
+            return self._results_of(mb, x, resid, t_disp, t_done)
+
+    def _results_of(self, mb: Microbatch, x, resid, t_disp: float,
+                    t_done: float) -> List[SolveResult]:
+        """Per-request slices and records of one materialized microbatch."""
         st = self.state
         stats = st.stats._replace(
             served=st.stats.served + jnp.asarray(mb.k, jnp.int32),
@@ -637,16 +679,11 @@ class AsyncSolveServer:
             self.registry.counter("serve.microbatches").inc()
             self.registry.histogram("serve.solve_latency_s").observe(
                 t_done - t_disp)
-        epoch_done_us = time.time() * 1e6 if self.tracer is not None else 0.0
-        if self.tracer is not None:
-            solve_us = (t_done - t_disp) * 1e6
-            self.tracer.add(
-                "device_solve", cat="solve", ts_us=epoch_done_us - solve_us,
-                dur_us=solve_us,
-                args={"k": mb.k, "uids": [r.uid for r in mb.requests],
-                      "tenant": mb.tenant})
         results = []
-        mb_resid = float(resid) if self.recorder is not None else None
+        mb_resid = None
+        if self.recorder is not None:
+            with self._sync("recorder_resid"):
+                mb_resid = float(resid)
         for j, req in enumerate(mb.requests):
             xj = tuple(xb[:, j] for xb in x) \
                 if isinstance(x, (tuple, list)) else x[:, j]
@@ -662,22 +699,19 @@ class AsyncSolveServer:
                     latency_s=t_done - req.t_submit,
                     residual=mb_resid if mb_resid >= 0 else None)
             if self.tracer is not None and queue_s is not None:
-                e2e_us = (t_done - req.t_submit) * 1e6
-                self.tracer.add(
-                    "queue_wait", cat="queue",
-                    ts_us=epoch_done_us - e2e_us, dur_us=queue_s * 1e6,
-                    trace=req.trace, args={"uid": req.uid})
-                self.tracer.add(
-                    "request", cat="serve",
-                    ts_us=epoch_done_us - e2e_us, dur_us=e2e_us,
-                    trace=req.trace, args={"uid": req.uid})
+                self.tracer.record("queue_wait", req.t_submit,
+                                   req.t_submit + queue_s, cat="queue",
+                                   trace=req.trace, args={"uid": req.uid})
+                self.tracer.record("request", req.t_submit, t_done,
+                                   cat="serve", trace=req.trace,
+                                   args={"uid": req.uid})
             results.append(SolveResult(uid=req.uid, x=xj,
                                        damping=req.damping,
                                        latency_s=t_done - req.t_submit))
         return results
 
     def _release(self, results: List[SolveResult]) -> None:
-        with self._cv:
+        with span("serve.release", tracer=self.tracer), self._cv:
             for r in results:
                 self._results[r.uid] = r
                 self._pending.discard(r.uid)
@@ -688,21 +722,20 @@ class AsyncSolveServer:
             return          # tenant rows went to the delta, not the window
         for req in mb.requests:
             if req.rows is not None:
-                self.state = self.adaptation.fold(self.state, req.rows)
+                self.state = self.adaptation.fold(self.state, req.rows,
+                                                  trace=req.trace)
 
     def _maybe_refresh(self) -> None:
-        self.state, refreshed = self.adaptation.maybe_refresh(
+        self.state, _ = self.adaptation.maybe_refresh(
             self.state, damping_state=self.damping_state)
         if self.registry is not None:
-            # age/residual were just pulled to host by the policy check —
-            # mirroring them into gauges costs no extra device sync
-            self.registry.gauge("curvature.factor_age").set(
-                int(self.state.age))
-            self.registry.gauge("curvature.last_drift_residual").set(
-                float(self.state.stats.last_residual))
-        if refreshed and self.tracer is not None:
-            self.tracer.add("refresh", cat="adapt",
-                            ts_us=time.time() * 1e6, dur_us=0.0)
+            # age/residual were just pulled to host by the policy check
+            # (a refresh replaced both with fresh arrays)
+            with self._sync("gauges"):
+                age = int(self.state.age)
+                resid = float(self.state.stats.last_residual)
+            self.registry.gauge("curvature.factor_age").set(age)
+            self.registry.gauge("curvature.last_drift_residual").set(resid)
         if self.recorder is not None:
             # maintenance boundary == the eager server's flush end: the
             # policy check just synchronized, so the recorder tick (and

@@ -150,7 +150,7 @@ class FleetWorker:
                 audit_every=adaptation.audit_every,
                 audit_probes=adaptation.audit_probes,
                 registry=self.registry, tracer=self.tracer,
-                profile=self.profile, health=self.health,
+                health=self.health,
                 recorder=self.recorder)
             # share the worker's journal so gossiped replays are recorded
             self.server.adaptation.journal = self.journal
@@ -188,8 +188,8 @@ class FleetWorker:
                     state, batcher=batcher, adaptation=adaptation,
                     policy=meta.get("policy", "cached"), jitter=jitter,
                     tenants=self.tenants, registry=self.registry,
-                    tracer=self.tracer, profile=self.profile,
-                    health=self.health, recorder=self.recorder)
+                    tracer=self.tracer, health=self.health,
+                    recorder=self.recorder)
             else:
                 self.server = SolveServer(
                     init_serve_state(S0, damping, jitter=jitter,
@@ -197,8 +197,8 @@ class FleetWorker:
                     batcher=batcher, adaptation=adaptation,
                     policy=meta.get("policy", "cached"), jitter=jitter,
                     tenants=self.tenants, registry=self.registry,
-                    tracer=self.tracer, profile=self.profile,
-                    health=self.health, recorder=self.recorder)
+                    tracer=self.tracer, health=self.health,
+                    recorder=self.recorder)
             if meta.get("restore_dir"):
                 restored, _ = restore_serve_state(
                     meta["restore_dir"], int(meta["restore_step"]),

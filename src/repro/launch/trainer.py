@@ -217,7 +217,7 @@ def build_server(cfg, *, mesh, window: int, seq: int, damping: float = 1e-3,
                  layout=None, async_: bool = False, oversize: str = "split",
                  window_dtype=None, tenant_rank=None, tenant_budget_mb=None,
                  seed: int = 0, audit_every: int = 0, audit_probes: int = 2,
-                 registry=None, tracer=None, profile=None, health=None,
+                 registry=None, tracer=None, health=None,
                  recorder=None, record_dir=None):
     """Config → mesh → model → resident curvature window → server.
 
@@ -245,9 +245,9 @@ def build_server(cfg, *, mesh, window: int, seq: int, damping: float = 1e-3,
     shared base factor; ``tenant_budget_mb`` caps resident tenant bytes
     (LRU spill past it).
 
-    ``registry`` / ``tracer`` / ``profile`` (``repro.obs``): thread the
-    observability fabric through the server — mergeable metrics, per-
-    request spans, optional ``jax.profiler`` capture around the solve.
+    ``registry`` / ``tracer`` (``repro.obs``): thread the observability
+    fabric through the server — mergeable metrics, and the server's
+    spans as Chrome events with per-request trace ids.
     ``health`` (``repro.obs.HealthMonitor``) attaches the numerical-health
     rule engine; ``audit_every`` runs the ``curvature.audit`` condest +
     residual probe every that many maintenance passes (0: off).
@@ -297,16 +297,14 @@ def build_server(cfg, *, mesh, window: int, seq: int, damping: float = 1e-3,
                                   adaptation=adaptation, policy=policy,
                                   jitter=jitter, tenants=tenants,
                                   registry=registry, tracer=tracer,
-                                  profile=profile, health=health,
-                                  recorder=recorder)
+                                  health=health, recorder=recorder)
     else:
         server = SolveServer(init_serve_state(S0, damping, jitter=jitter,
                                               window_dtype=window_dtype),
                              batcher=batcher, adaptation=adaptation,
                              policy=policy, jitter=jitter, tenants=tenants,
                              registry=registry, tracer=tracer,
-                             profile=profile, health=health,
-                             recorder=recorder)
+                             health=health, recorder=recorder)
     return server, handles
 
 

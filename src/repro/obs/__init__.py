@@ -2,14 +2,21 @@
 
 - ``metrics``: process-wide registry of counters/gauges/fixed-bucket
   histograms whose snapshots merge across processes (fleet view).
-- ``trace``: per-request span tracing with cross-process trace ids and
-  Chrome-trace/Perfetto JSON export.
+- ``trace``: ``span``, the one way the program records a span. Spans
+  are always on and always on the profiler's clock: each is a
+  ``jax.profiler`` TraceMe (one check when no capture runs), so a
+  capture holds them beside the device's operations, with their
+  arguments (uid lists joined by spaces). With a ``Tracer`` attached the
+  same span is also a Chrome event with a cross-process trace id, for
+  Chrome-trace/Perfetto JSON export. ``host_sync`` spans a blocking
+  device-to-host read and counts it in ``serve.host_syncs``.
 - ``export``: stdlib HTTP endpoint (Prometheus text + JSON + /health)
   and snapshot files next to checkpoints.
 - ``health``: rule engine over registry series — structured
   ``HealthEvent`` log + per-process ``ok``/``degraded``/``critical``
   verdicts that merge across a fleet.
-- ``profile``: optional ``jax.profiler`` hooks around the solve.
+- ``profile``: optional ``jax.profiler`` capture (``--profile-dir``);
+  it adds no annotation of its own, the spans above are what it shows.
 - ``recorder``: bounded flight recorder — request digests, journal
   tail, cadenced state fingerprints — flushed to atomic incident
   bundles on health-verdict escalations.
@@ -38,7 +45,7 @@ from .metrics import (
 )
 from .profile import ProfileHooks
 from .recorder import FlightRecorder
-from .trace import Span, Tracer
+from .trace import Span, Tracer, host_sync, span
 
 __all__ = [
     "Counter",
@@ -56,12 +63,14 @@ __all__ = [
     "analyze",
     "default_buckets",
     "default_rules",
+    "host_sync",
     "load_bundle",
     "merge",
     "merge_health",
     "prometheus_text",
     "quantile",
     "registry",
+    "span",
     "start_metrics_server",
     "write_snapshot",
 ]

@@ -1,27 +1,25 @@
-"""Optional ``jax.profiler`` capture hooks around the coalesced solve.
+"""Optional ``jax.profiler`` capture for ``--profile-dir``.
 
-Kernel-level drill-down for when the span tracer says "device solve"
-is the slow stage but not *why*.  Every method is a no-op when profiling
-is off, so the serving hot path carries a single ``if`` when disabled.
-When a trace directory was asked for, a failed ``start_trace`` or
+Kernel-level drill-down: the capture holds the device's operations and,
+on the same clock, the spans the program always writes (``obs.trace``:
+``serve.microbatch`` step spans, ``serve.dispatch``, ``adapt.refresh``,
+``host.sync`` ...). Every method is a no-op when profiling is off. When a
+trace directory was asked for, a failed ``start_trace`` or
 ``stop_trace`` raises: a run that was meant to be profiled does not go
 on without its trace.
 """
 
 from __future__ import annotations
 
-import contextlib
-
 __all__ = ["ProfileHooks"]
 
 
 class ProfileHooks:
-    """Gated wrapper over ``jax.profiler`` trace + step annotations.
+    """Gated ``jax.profiler`` capture.
 
     ``ProfileHooks(log_dir)`` starts a profiler trace into ``log_dir``
-    on ``start()`` and annotates each coalesced solve with a
-    ``StepTraceAnnotation`` so devices steps line up in the viewer.
-    With ``log_dir=None`` every method is a no-op.
+    on ``start()`` and stops it on ``stop()``. With ``log_dir=None``
+    every method is a no-op.
     """
 
     def __init__(self, log_dir: str | None = None) -> None:
@@ -43,12 +41,3 @@ class ProfileHooks:
 
         self._active = False
         jax.profiler.stop_trace()
-
-    def step(self, name: str = "coalesced_solve", step: int | None = None):
-        """Context manager annotating one solve; no-op when inactive."""
-        if not self._active:
-            return contextlib.nullcontext()
-        import jax
-
-        kwargs = {} if step is None else {"step_num": step}
-        return jax.profiler.StepTraceAnnotation(name, **kwargs)

@@ -1,23 +1,31 @@
-"""Per-request span tracing with Chrome-trace/Perfetto export.
+"""Spans on the profiler's clock, with Chrome-trace/Perfetto export.
+
+Every span the program records goes through ``span``. It always opens a
+``jax.profiler.TraceAnnotation`` (a TraceMe): with no profiler capture
+running that costs one check, and under a capture the span lands on the
+host plane of the ``.xplane.pb`` beside the device's operations, on the
+same timeline, with its arguments as metadata. Argument strings are built
+only while a capture records. The profiler cuts a value at ``,``, ``#``
+or ``=``, so those become spaces, and lists (request uids) are joined
+with spaces: ``uids="3 4 5"``.
 
 A request's life is submit → queue → coalesce → dispatch → device
 solve → fold → respond, and under the fleet those stages happen in
-*different processes*.  The tracer records complete spans ("X" phase
-events in Chrome trace format) stamped with a shared ``trace`` id; the
-dispatcher puts the id on the solve frame, the worker tags its spans
-with the same id and ships them back on the result frame, and
-``export`` writes one JSON all the spans stitch together in.
+*different processes*. With a ``Tracer`` attached, the same ``span``
+also records a complete Chrome event ("X" phase) stamped with a shared
+``trace`` id; the dispatcher puts the id on the solve frame, the worker
+tags its spans with the same id and ships them back on the result
+frame, and ``export`` writes one JSON all the spans stitch together in.
 
-Timestamps are epoch microseconds (``time.time``-based) so spans from
-different processes land on one timeline; durations are measured with
-``perf_counter`` for resolution.  The event buffer is a bounded deque
-— a long-lived server keeps the most recent window, never grows
-without limit.
+Chrome timestamps are epoch microseconds, so spans from different
+processes land on one timeline. They are the span's own ``perf_counter``
+readings plus one offset to ``time.time`` fixed when the ``Tracer`` is
+created. The event buffer is a bounded deque — a long-lived server
+keeps the most recent window, never grows without limit.
 """
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import threading
@@ -25,21 +33,85 @@ import time
 from collections import deque
 from typing import Iterable
 
-__all__ = ["Tracer", "Span"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Tracer", "Span", "span", "host_sync"]
+
+# the profiler's metadata ends a value at these characters
+_CUT = str.maketrans({",": " ", "#": " ", "=": " "})
+
+
+def _arg(v):
+    """One span argument as the profiler's metadata holds it."""
+    if isinstance(v, (int, float)):
+        return v
+    if isinstance(v, (list, tuple)):
+        v = " ".join(str(x) for x in v)
+    return str(v).translate(_CUT)
+
+
+def _annotation(name: str, args: dict) -> TraceAnnotation:
+    if not args or not TraceAnnotation.is_enabled():
+        return TraceAnnotation(name)
+    meta = {k: _arg(v) for k, v in args.items()}
+    if "step_num" in args:
+        meta["_r"] = 1          # a step span, as StepTraceAnnotation marks it
+    return TraceAnnotation(name, **meta)
 
 
 class Span:
-    """Handle for an open span; finished via the Tracer context manager."""
+    """One span: a TraceMe on the calling thread and, with a ``Tracer``,
+    a Chrome event from the same ``perf_counter`` readings."""
 
-    __slots__ = ("name", "cat", "trace", "args", "ts_us", "_t0")
+    __slots__ = ("name", "cat", "tracer", "trace", "args", "_me", "_t0")
 
-    def __init__(self, name: str, cat: str, trace: str | None, args: dict | None):
+    def __init__(self, name: str, tracer: "Tracer | None",
+                 trace: str | None, args: dict, cat: str | None = None):
         self.name = name
-        self.cat = cat
+        self.cat = cat if cat is not None else name.split(".", 1)[0]
+        self.tracer = tracer
         self.trace = trace
         self.args = args
-        self.ts_us = time.time() * 1e6
-        self._t0 = time.perf_counter()
+
+    def __enter__(self) -> "Span":
+        self._me = _annotation(self.name, self.args)
+        self._me.__enter__()
+        if self.tracer is not None:
+            self._t0 = time.perf_counter()
+        return self
+
+    def set(self, **args) -> None:
+        """Arguments known only inside the span, such as a decision."""
+        self.args.update(args)
+        if TraceAnnotation.is_enabled():
+            self._me.set_metadata(**{k: _arg(v) for k, v in args.items()})
+
+    def __exit__(self, *exc) -> bool:
+        if self.tracer is not None:
+            self.tracer.record(self.name, self._t0, time.perf_counter(),
+                               cat=self.cat, trace=self.trace,
+                               args=self.args)
+        self._me.__exit__(*exc)
+        return False
+
+
+def span(name: str, *, tracer: "Tracer | None" = None,
+         trace: str | None = None, **args) -> Span:
+    """Context manager: one span named ``name`` on the profiler's clock.
+
+    ``args`` become the span's metadata (``uids=[3, 4]`` reads back as
+    ``"3 4"``); ``step_num=`` makes it a step span. With ``tracer`` the
+    span is also a Chrome event, tagged with ``trace``, of category
+    ``name``'s first dotted part."""
+    return Span(name, tracer, trace, args)
+
+
+def host_sync(site: str, *, registry=None, tracer=None) -> Span:
+    """Span ``host.sync`` around one blocking device-to-host read, and one
+    more on the registry's ``serve.host_syncs`` counter."""
+    if registry is not None:
+        registry.counter("serve.host_syncs").inc()
+    return Span("host.sync", tracer, None, {"site": site})
 
 
 class Tracer:
@@ -50,6 +122,8 @@ class Tracer:
         self._events: deque[dict] = deque(maxlen=max_events)
         self._pending: deque[dict] = deque(maxlen=max_events)
         self.pid = os.getpid() if pid is None else pid
+        # perf_counter seconds -> epoch seconds, fixed once
+        self._epoch_s = time.time() - time.perf_counter()
 
     def add(
         self,
@@ -82,7 +156,12 @@ class Tracer:
             self._events.append(ev)
             self._pending.append(ev)
 
-    @contextlib.contextmanager
+    def record(self, name: str, t0: float, t1: float, *, cat: str,
+               trace: str | None = None, args: dict | None = None) -> None:
+        """Record a span between two ``perf_counter`` readings."""
+        self.add(name, cat=cat, ts_us=(t0 + self._epoch_s) * 1e6,
+                 dur_us=(t1 - t0) * 1e6, trace=trace, args=args)
+
     def span(
         self,
         name: str,
@@ -90,20 +169,9 @@ class Tracer:
         cat: str = "serve",
         trace: str | None = None,
         args: dict | None = None,
-    ):
-        s = Span(name, cat, trace, args)
-        try:
-            yield s
-        finally:
-            dur_us = (time.perf_counter() - s._t0) * 1e6
-            self.add(
-                s.name,
-                cat=s.cat,
-                ts_us=s.ts_us,
-                dur_us=dur_us,
-                trace=s.trace,
-                args=s.args,
-            )
+    ) -> Span:
+        """``span(name, tracer=self, ...)`` with an explicit category."""
+        return Span(name, self, trace, dict(args) if args else {}, cat=cat)
 
     def ingest(self, events: Iterable[dict]) -> None:
         """Adopt spans recorded by another process (they keep their pid)."""

@@ -42,6 +42,7 @@ from repro.core.operator import BlockedScores, is_blocked
 from repro.core.solvers import chol_factorize
 from repro.curvature.update import chol_downdate, chol_update, replace_factors
 from repro.kernels import ops as kernel_ops
+from repro.obs.trace import host_sync, span
 from repro.serve.state import ServeState, serve_mode
 
 __all__ = ["OnlineAdaptation", "pad_to_window_cols"]
@@ -186,6 +187,9 @@ class OnlineAdaptation:
         self.journal = journal
         self.on_fold = on_fold
         self._dist_fns = {}            # (kind, mode) -> jitted shard_map fn
+        # optional repro.obs.Tracer, set by the server that holds this
+        # policy: the adapt.* spans then also become Chrome events
+        self.tracer = None
 
     @classmethod
     def from_policy(cls, policy, *, jitter: Optional[float] = None
@@ -204,7 +208,8 @@ class OnlineAdaptation:
         return None
 
     def fold(self, state: ServeState, rows, *, slots=None,
-             record: bool = True) -> ServeState:
+             record: bool = True, trace: Optional[str] = None
+             ) -> ServeState:
         """Fold one request's score rows into the window (FIFO replace).
 
         ``rows``: (k, m) dense — or a tuple of per-block (k, m_b) pieces
@@ -219,10 +224,16 @@ class OnlineAdaptation:
 
         ``record=False`` suppresses journal/on_fold emission (used by the
         replayer itself so ingested events aren't re-logged as local).
+        ``trace`` tags the ``adapt.fold`` span with a request's trace id.
         """
         row_blocks = tuple(rows) if isinstance(rows, (tuple, list)) \
             else (rows,)
         k = int(row_blocks[0].shape[0])
+        with span("adapt.fold", tracer=self.tracer, trace=trace, rows=k):
+            return self._fold(state, rows, row_blocks, k, slots, record)
+
+    def _fold(self, state: ServeState, rows, row_blocks, k: int, slots,
+              record: bool) -> ServeState:
         n = self.fifo_n if self.fifo_n is not None \
             else int(state.W.shape[0])
         if k > n:
@@ -235,7 +246,8 @@ class OnlineAdaptation:
                            or self.on_fold is not None)
         if slots is not None or emit:
             # host-side cursor read: only when an event identity is needed
-            cursor = int(state.slot)
+            with self._sync("fold_cursor"):
+                cursor = int(state.slot)
             expect = tuple((cursor + i) % n for i in range(k))
             if slots is not None and tuple(int(s) for s in slots) != expect:
                 raise ValueError(
@@ -299,8 +311,10 @@ class OnlineAdaptation:
                 self.on_fold(ev)
         return state._replace(S=Sp, W=Wp, L=Lp, slot=slot, stats=stats)
 
-    @staticmethod
-    def _rows_finite(rows_in) -> bool:
+    def _sync(self, site: str):
+        return host_sync(site, registry=self.registry, tracer=self.tracer)
+
+    def _rows_finite(self, rows_in) -> bool:
         """One fused isfinite reduction over the (already device-resident)
         fold rows. The host read rides the same boundary as the journal's
         cursor read — a scalar pull, not a data transfer."""
@@ -309,7 +323,8 @@ class OnlineAdaptation:
         ok = jnp.asarray(True)
         for b in blocks:
             ok = jnp.logical_and(ok, jnp.all(jnp.isfinite(b)))
-        return bool(ok)
+        with self._sync("rows_finite"):
+            return bool(ok)
 
     def _window_gauges(self, S) -> None:
         """Window storage by dtype — shape/dtype metadata only, no device
@@ -348,37 +363,56 @@ class OnlineAdaptation:
         """Full W refactorization when the staleness bound is hit — called
         between microbatches, never on the request path. Returns
         (state', refreshed)."""
-        tol = self.effective_drift_tol(damping_state)
-        r = float(state.stats.last_residual)
-        age_due = int(state.age) >= self.refresh_every
-        drift_due = tol is not None and r >= 0.0 and r > float(tol)
-        refreshed = force or age_due or drift_due
+        with span("adapt.policy", tracer=self.tracer) as policy:
+            reason = self._refresh_reason(state, damping_state, force)
+            policy.set(reason=reason)
+        refreshed = reason != "none"
         if refreshed:
-            if record and self.journal is not None:
-                self.journal.append_refresh()
-            if self.dist is not None:
-                W, L = self._dist_fn("refresh", serve_mode(state))(
-                    state.S, state.lam0)
-            else:
-                fac = chol_factorize(state.S, state.lam0,
-                                     mode=serve_mode(state),
-                                     jitter=self.jitter)
-                W, L = fac.W, fac.L
-            stats = state.stats._replace(
-                refreshes=state.stats.refreshes + 1,
-                last_residual=-jnp.ones((), jnp.float32))
-            if self.registry is not None:
-                self.registry.counter("curvature.refreshes").inc()
-                reason = "force" if force else ("age" if age_due else "drift")
-                self.registry.counter(f"curvature.refresh_{reason}").inc()
-            state = state._replace(W=W, L=L,
-                                   age=jnp.zeros((), jnp.int32),
-                                   stats=stats)
+            with span("adapt.refresh", tracer=self.tracer, reason=reason):
+                state = self._refresh(state, reason, record)
         # we are at the maintenance host-sync boundary anyway — drain the
         # pending downdate margins, run the periodic factor audit, and
         # let the health rules look at the fresh numbers
-        self._observe_health(state)
+        with span("adapt.health", tracer=self.tracer):
+            self._observe_health(state)
         return state, refreshed
+
+    def _refresh_reason(self, state: ServeState, damping_state,
+                        force: bool) -> str:
+        """"force", "age", "drift" or "none": the staleness decision."""
+        tol = self.effective_drift_tol(damping_state)
+        with self._sync("last_residual"):
+            r = float(state.stats.last_residual)
+        with self._sync("age"):
+            age_due = int(state.age) >= self.refresh_every
+        drift_due = False
+        if tol is not None and r >= 0.0:
+            with self._sync("drift_tol"):
+                drift_due = r > float(tol)
+        if force:
+            return "force"
+        return "age" if age_due else "drift" if drift_due else "none"
+
+    def _refresh(self, state: ServeState, reason: str,
+                 record: bool) -> ServeState:
+        if record and self.journal is not None:
+            self.journal.append_refresh()
+        if self.dist is not None:
+            W, L = self._dist_fn("refresh", serve_mode(state))(
+                state.S, state.lam0)
+        else:
+            fac = chol_factorize(state.S, state.lam0,
+                                 mode=serve_mode(state),
+                                 jitter=self.jitter)
+            W, L = fac.W, fac.L
+        stats = state.stats._replace(
+            refreshes=state.stats.refreshes + 1,
+            last_residual=-jnp.ones((), jnp.float32))
+        if self.registry is not None:
+            self.registry.counter("curvature.refreshes").inc()
+            self.registry.counter(f"curvature.refresh_{reason}").inc()
+        return state._replace(W=W, L=L, age=jnp.zeros((), jnp.int32),
+                              stats=stats)
 
     def _observe_health(self, state: ServeState) -> None:
         """Materialize pending downdate margins + run the audit cadence.
@@ -408,8 +442,11 @@ class OnlineAdaptation:
                         split = i
                         break
             done, self._pending_aux = pending[:split], pending[split:]
-            margins = [float(a.margin) for a in done]
-            clamped = sum(bool(a.clamped) for a in done)
+            margins, clamped = [], 0
+            for a in done:
+                with self._sync("margin"):
+                    margins.append(float(a.margin))
+                    clamped += bool(a.clamped)
             vals = [v for v in margins if v == v]      # NaN-proof min
             if vals:
                 self.registry.gauge(
@@ -421,7 +458,8 @@ class OnlineAdaptation:
             self._audit_tick += 1
             if self._audit_tick >= self.audit_every:
                 self._audit_tick = 0
-                self.audit(state)
+                with span("adapt.audit", tracer=self.tracer):
+                    self.audit(state)
         if self.health is not None:
             self.health.evaluate()
 
@@ -441,8 +479,9 @@ class OnlineAdaptation:
         self._audit_step += 1
         res = self._audit_fn(state.W, state.L, state.lam0,
                              step=self._audit_step)
-        out = {"condest": float(res.condest),
-               "residual": float(res.residual)}
+        with self._sync("audit"):
+            out = {"condest": float(res.condest),
+                   "residual": float(res.residual)}
         if self.registry is not None:
             self.registry.gauge("curvature.condest").set(out["condest"])
             self.registry.gauge(

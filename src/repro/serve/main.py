@@ -133,8 +133,9 @@ def serve_main(argv=None):
                          "Chrome-trace JSON here at exit (--fleet: spans "
                          "from every worker stitch into one file)")
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
-                    help="capture a jax.profiler trace of the coalesced "
-                         "solves into DIR (--fleet: each worker writes "
+                    help="capture a jax.profiler trace of the serving "
+                         "run into DIR, with the server's spans on the "
+                         "device's clock (--fleet: each worker writes "
                          "its own trace under DIR/worker<i>/)")
     ap.add_argument("--audit-every", type=int, default=4, metavar="K",
                     help="run the curvature.audit condition estimate + "
@@ -197,7 +198,7 @@ def serve_main(argv=None):
         tenant_rank=args.tenant_rank if args.tenants else None,
         tenant_budget_mb=args.tenant_budget_mb, seed=args.seed,
         audit_every=args.audit_every,
-        registry=registry, tracer=tracer, profile=profile, health=health,
+        registry=registry, tracer=tracer, health=health,
         recorder=recorder)
     endpoint_port = _start_endpoint(args, registry, health=health.report)
     kind = f"async {layout or 'replicated'}" if async_ else "eager"

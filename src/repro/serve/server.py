@@ -33,7 +33,6 @@ against the shared base.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import time
 from collections import deque
@@ -45,6 +44,7 @@ import numpy as np
 
 from repro.core.solvers import CholFactorization, chol_factorize
 from repro.kernels import ops as kernel_ops
+from repro.obs.trace import span
 from repro.serve.adapt import OnlineAdaptation
 from repro.serve.batcher import Microbatch, TokenBudgetBatcher
 from repro.serve.state import ServeState, as_factorization, serve_mode
@@ -192,10 +192,11 @@ class SolveServer:
         latency/queue-wait histograms, stage counters, and queue gauges
         land there (mergeable across processes). None: wall-clock summary
         only, zero registry overhead.
-      tracer: optional ``repro.obs.Tracer`` — per-request queue/solve/
-        fold spans, trace ids riding ``submit(trace=)``.
-      profile: optional ``repro.obs.ProfileHooks`` — ``jax.profiler``
-        step annotation around the coalesced solve.
+      tracer: optional ``repro.obs.Tracer`` — the spans the server
+        always writes to the profiler (``serve.dispatch``, ``serve.wait``,
+        the adaptation's ``adapt.*``) also become Chrome events, beside
+        per-request ``request``/``queue_wait`` events; trace ids ride
+        ``submit(trace=)``.
       health: optional ``repro.obs.HealthMonitor`` — propagated to the
         adaptation (margin/audit events) and re-evaluated per flush, so
         the verdict tracks the freshest numerical-health gauges.
@@ -212,7 +213,7 @@ class SolveServer:
                  policy: str = "cached", monitor_drift: bool = True,
                  jitter: float = 0.0, fused: bool = True,
                  tenants=None, clock=time.perf_counter,
-                 registry=None, tracer=None, profile=None, health=None,
+                 registry=None, tracer=None, health=None,
                  recorder=None, metrics_window: int = 4096):
         if policy not in ("cached", "refactorize"):
             raise ValueError(f"policy must be 'cached' or 'refactorize', "
@@ -228,7 +229,6 @@ class SolveServer:
         self.clock = clock
         self.registry = registry
         self.tracer = tracer
-        self.profile = profile
         self.health = health
         self.recorder = recorder
         self.metrics = ServerMetrics(window=metrics_window,
@@ -243,6 +243,9 @@ class SolveServer:
         if health is not None and adaptation is not None \
                 and getattr(adaptation, "health", None) is None:
             adaptation.health = health
+        if tracer is not None and adaptation is not None \
+                and getattr(adaptation, "tracer", None) is None:
+            adaptation.tracer = tracer
 
     # -- request intake ----------------------------------------------------
     def submit(self, v, *, damping: Optional[float] = None, tokens: int = 1,
@@ -300,20 +303,11 @@ class SolveServer:
                     # never the shared window
                     self.tenants.fold(self.state, mb.tenant, req.rows)
                 elif self.adaptation is not None:
-                    if self.tracer is not None:
-                        with self.tracer.span("fold", cat="adapt",
-                                              trace=req.trace):
-                            self.state = self.adaptation.fold(self.state,
-                                                              req.rows)
-                    else:
-                        self.state = self.adaptation.fold(self.state,
-                                                          req.rows)
+                    self.state = self.adaptation.fold(self.state, req.rows,
+                                                      trace=req.trace)
             if self.adaptation is not None:
-                self.state, refreshed = self.adaptation.maybe_refresh(
+                self.state, _ = self.adaptation.maybe_refresh(
                     self.state, damping_state=damping_state)
-                if refreshed and self.tracer is not None:
-                    self.tracer.add("refresh", cat="adapt",
-                                    ts_us=time.time() * 1e6, dur_us=0.0)
             if self.registry is not None:
                 self._health_gauges()
         if self.health is not None:
@@ -381,9 +375,8 @@ class SolveServer:
         st = self.state
         lam0 = float(st.lam0)
         t_start = self.clock()
-        step_ctx = self.profile.step(step=self.metrics.served) \
-            if self.profile is not None else contextlib.nullcontext()
-        with step_ctx:
+        with span("serve.dispatch", tracer=self.tracer, k=mb.k,
+                  uids=[r.uid for r in mb.requests]):
             if mb.tenant is not None:
                 x = self._serve_tenant(mb)
                 resid = -jnp.ones((), jnp.float32)
@@ -394,6 +387,7 @@ class SolveServer:
                     mode=serve_mode(st), jitter=self.jitter, uniform=uniform,
                     monitor=self.monitor_drift and self.policy == "cached",
                     refactorize=self.policy == "refactorize", fused=self.fused)
+        with span("serve.wait", tracer=self.tracer):
             jax.block_until_ready(x)
         t_done = self.clock()
 
@@ -409,17 +403,6 @@ class SolveServer:
             self.registry.counter("serve.microbatches").inc()
             self.registry.histogram("serve.solve_latency_s").observe(
                 t_done - t_start)
-        if self.tracer is not None:
-            # one epoch anchor per microbatch: spans from every process
-            # land on the time.time() timeline while durations stay on
-            # the monotonic clock that stamped t_submit/t_done
-            epoch_done_us = time.time() * 1e6
-            solve_us = (t_done - t_start) * 1e6
-            self.tracer.add(
-                "device_solve", cat="solve", ts_us=epoch_done_us - solve_us,
-                dur_us=solve_us,
-                args={"k": k, "uids": [r.uid for r in mb.requests],
-                      "tenant": mb.tenant})
 
         results = []
         mb_resid = float(resid) if self.recorder is not None else None
@@ -438,15 +421,12 @@ class SolveServer:
                     latency_s=t_done - req.t_submit,
                     residual=mb_resid if mb_resid >= 0 else None)
             if self.tracer is not None and queue_s is not None:
-                e2e_us = (t_done - req.t_submit) * 1e6
-                self.tracer.add(
-                    "queue_wait", cat="queue",
-                    ts_us=epoch_done_us - e2e_us, dur_us=queue_s * 1e6,
-                    trace=req.trace, args={"uid": req.uid})
-                self.tracer.add(
-                    "request", cat="serve",
-                    ts_us=epoch_done_us - e2e_us, dur_us=e2e_us,
-                    trace=req.trace, args={"uid": req.uid})
+                self.tracer.record("queue_wait", req.t_submit,
+                                   req.t_submit + queue_s, cat="queue",
+                                   trace=req.trace, args={"uid": req.uid})
+                self.tracer.record("request", req.t_submit, t_done,
+                                   cat="serve", trace=req.trace,
+                                   args={"uid": req.uid})
             results.append(SolveResult(uid=req.uid, x=xj,
                                        damping=req.damping,
                                        latency_s=t_done - req.t_submit))

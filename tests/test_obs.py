@@ -193,7 +193,11 @@ def test_server_records_queue_wait_and_health_gauges():
     # refresh_every=2 -> the age policy fired at least once
     assert snap["counters"].get("curvature.refreshes", 0) >= 1
     names = {e["name"] for e in tracer.events()}
-    assert {"request", "queue_wait", "device_solve", "fold"} <= names
+    assert {"request", "queue_wait", "serve.dispatch", "serve.wait",
+            "adapt.fold", "adapt.refresh"} <= names
+    refresh = [e for e in tracer.events() if e["name"] == "adapt.refresh"]
+    assert all(e["args"]["reason"] == "age" and e["dur"] > 0
+               for e in refresh)
 
 
 def test_batcher_queue_stats():
