@@ -157,14 +157,51 @@ def center_scores(O: jax.Array, *, weights: Optional[jax.Array] = None) -> jax.A
     return jnp.sqrt(weights)[:, None] * (O - mean)
 
 
-def gram(S, *, mode: str = "real", precision=_HI) -> jax.Array:
-    """W = S·Sᵀ (or S·S† in complex mode), fp32/fp64 accumulation.
+_SYRK_PANELS = 8        # block columns the panel width aims for
 
-    Accepts the dense (n, m) array or a blocked operator (block-wise
-    accumulation, no concatenation)."""
+
+@functools.partial(jax.jit, static_argnames=("mode", "precision"))
+def _gram_syrk(S: jax.Array, *, mode: str, precision) -> jax.Array:
+    """Lower block triangle of S·Sᵀ (S·S†), one matmul per block column
+    over the parameter axis, mirrored into a full, exactly symmetric
+    (Hermitian) W. One program, so the row slices of S feed the dots and
+    are never copied; each column is written into W in place (an update
+    slice compiles for the TPU in ~3 s less than padding and
+    concatenating the columns)."""
+    n = S.shape[0]
+    # a multiple of 128 rows giving ~8 block columns: 256 at n = 2048,
+    # the fastest of 128–1024 there on a TPU v5e; one panel for n ≤ 128
+    panel = min(n, 128 * max(1, -(-n // (128 * _SYRK_PANELS))))
+    with jax.named_scope("gram_syrk"):
+        Wl = jnp.zeros((n, n), S.dtype)
+        for j0 in range(0, n, panel):
+            P = S[j0:j0 + panel]
+            if mode == "complex":
+                P = P.conj()
+            C = jax.lax.dot_general(S[j0:], P, (((1,), (1,)), ((), ())),
+                                    precision=precision)
+            Wl = jax.lax.dynamic_update_slice(Wl, C, (j0, j0))
+        r = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+        c = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+        W = jnp.where(r > c, Wl, _ct(Wl, mode))
+        if mode == "complex":
+            W = jnp.where(r == c, W.real.astype(W.dtype), W)
+    return W
+
+
+def gram(S, *, mode: str = "real", precision=_HI) -> jax.Array:
+    """W = S·Sᵀ (or S·S† in complex mode) at ``precision``, fp32/fp64
+    accumulation.
+
+    A dense (n, m) S is a symmetric rank-k product: only the lower block
+    triangle is computed, in ⌈n/b⌉ panels of b rows (b a multiple of 128
+    giving ~8 block columns, 56% of the full product's multiply-adds at
+    n = 2048; a single panel, one full dot, for n ≤ 128), and mirrored,
+    so W is exactly symmetric (Hermitian with a real diagonal). A blocked
+    operator accumulates block-wise, with no concatenation."""
     if is_blocked(S):
         return S.gram(mode=mode, precision=precision)
-    return jnp.matmul(S, _ct(S, mode), precision=precision)
+    return _gram_syrk(S, mode=mode, precision=precision)
 
 
 def gram_chunked(S: jax.Array, chunk: int, *, mode: str = "real",

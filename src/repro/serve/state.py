@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.operator import BlockedScores, is_blocked
-from repro.core.solvers import CholFactorization, chol_factorize
+from repro.core.solvers import CholFactorization, chol_factorize, gram
 
 __all__ = ["ServeStats", "ServeState", "init_serve_state", "serve_mode",
            "as_factorization", "save_serve_state", "restore_serve_state",
@@ -136,11 +136,8 @@ def init_serve_state(S, damping, *, jitter: float = 0.0,
         S_in = S_in.realify() if is_blocked(S_in) else \
             jnp.concatenate([jnp.real(S_in), jnp.imag(S_in)], axis=0)
     S_store = S_in.astype(wd)
-    W = S_store.gram() if is_blocked(S_store) else None
-    if W is None:
-        acc = jnp.promote_types(wd, jnp.float32)
-        W = jnp.matmul(S_store.astype(acc), S_store.astype(acc).T,
-                       precision=_HI)
+    W = S_store.gram() if is_blocked(S_store) else gram(
+        S_store.astype(jnp.promote_types(wd, jnp.float32)), precision=_HI)
     lam = jnp.asarray(damping, W.dtype)
     n = W.shape[0]
     L = jnp.linalg.cholesky(

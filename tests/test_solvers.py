@@ -15,6 +15,7 @@ from repro.core import (
     chol_solve,
     direct_solve,
     eigh_solve,
+    gram,
     gram_chunked,
     get_solver,
     minsr_solve,
@@ -37,9 +38,14 @@ def make_problem(n=24, m=150, lam=0.1, dtype=jnp.float32, complex_=False,
     return jnp.asarray(S, dtype), jnp.asarray(v, dtype), lam
 
 
-@pytest.mark.parametrize("name", sorted(SOLVERS))
-def test_solver_matches_direct(name):
-    S, v, lam = make_problem()
+@pytest.mark.parametrize(
+    "name,n,m,lam",
+    [pytest.param(nm, 24, 150, 0.1, id=nm) for nm in sorted(SOLVERS)]
+    # n = 520 takes several Gram panels (5 of 128, the last ragged)
+    + [pytest.param(nm, 520, 640, 10.0, id=nm + "-panels")
+       for nm in ("chol", "eigh")])
+def test_solver_matches_direct(name, n, m, lam):
+    S, v, lam = make_problem(n=n, m=m, lam=lam)
     x_ref = direct_solve(S, v, lam)
     x = get_solver(name)(S, v, lam)
     np.testing.assert_allclose(np.asarray(x), np.asarray(x_ref),
@@ -136,6 +142,49 @@ def test_gram_chunked_complex_accumulation_dtype():
     Sr, _, _ = make_problem(n=8, m=45)
     Wr = gram_chunked(Sr.astype(jnp.bfloat16), 16)
     assert Wr.dtype == jnp.float32
+
+
+def _dot_macs(jaxpr):
+    """Multiply-adds of every dot_general in a jaxpr, sub-jaxprs included."""
+    macs = []
+    for eq in jaxpr.eqns:
+        if eq.primitive.name == "dot_general":
+            (contract, _), _ = eq.params["dimension_numbers"]
+            k = int(np.prod([eq.invars[0].aval.shape[d] for d in contract]))
+            macs.append(int(np.prod(eq.outvars[0].aval.shape)) * k)
+        for p in eq.params.values():
+            sub = getattr(p, "jaxpr", p)
+            if hasattr(sub, "eqns"):
+                macs += _dot_macs(sub)
+    return macs
+
+
+@pytest.mark.parametrize("complex_", [False, True], ids=["real", "complex"])
+@pytest.mark.parametrize("n,m,panels", [(100, 3000, 1), (200, 3000, 2),
+                                        (512, 3000, 4), (600, 3000, 5),
+                                        (2048, 200, 8)],
+                         ids=["plain", "small", "exact", "ragged", "table1"])
+def test_gram_symmetric_panels(n, m, panels, complex_):
+    """The dense Gram computes only the lower block triangle, one dot per
+    block column of ⌈n/b⌉ (one full dot for n ≤ 128), and mirrors it: W
+    matches the full HIGHEST product, is exactly symmetric (Hermitian),
+    and at n = 2048 costs ≤ 0.6× the full product's multiply-adds."""
+    mode = "complex" if complex_ else "real"
+    S, _, _ = make_problem(n=n, m=m, complex_=complex_, seed=n)
+    W = gram(S, mode=mode)
+    Wr = jnp.matmul(S, S.conj().T if complex_ else S.T,
+                    precision=jax.lax.Precision.HIGHEST)
+    assert W.dtype == Wr.dtype
+    scale = float(jnp.max(jnp.abs(Wr)))
+    assert float(jnp.max(jnp.abs(W - Wr))) <= 5e-6 * scale
+    assert bool(jnp.all(W == (W.conj().T if complex_ else W.T)))
+
+    macs = _dot_macs(jax.make_jaxpr(lambda S: gram(S, mode=mode))(S).jaxpr)
+    assert len(macs) == panels
+    if panels > 1:
+        assert sum(macs) < n * n * m
+    if n == 2048:
+        assert sum(macs) <= 0.6 * n * n * m
 
 
 def test_bf16_scores_promote():

@@ -10,12 +10,14 @@ not a fallback to the reference.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import chol_solve
 from repro.kernels import ops
 
 # serve window at the benchmark's real shape: n samples, m parameters
@@ -108,3 +110,23 @@ def test_cholupdate_compiles(one_chip, sign, n):
 def test_cholesky_compiles(one_chip, n):
     _compile(functools.partial(ops.cholesky, mode="kernel"), one_chip,
              ((n, n), jnp.float32))
+
+
+def test_table1_solve_gram_reads_s_in_place(one_chip):
+    """Algorithm 1 at the Table-1 shape (n=2048, m=100,000): the Gram is
+    8 panel dots at HIGHEST (the lower block triangle), and the program
+    makes no copy, slice or pad of S outside the dots' own fusions."""
+    n, m = 2048, 100_000
+    args = [jax.ShapeDtypeStruct((n, m), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((m,), jnp.float32, sharding=one_chip)]
+    text = jax.jit(lambda S, v: chol_solve(S, v, 1e-3)).lower(
+        *args).compile().as_text()
+    dots = [ln for ln in text.splitlines()
+            if " convolution(" in ln and "gram_syrk/dot_general" in ln]
+    assert len(dots) == 8
+    assert all("operand_precision={highest,highest}" in ln for ln in dots)
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    made = [ln for ln in entry.splitlines()[1:]
+            if re.search(rf"= f32\[\d+,{m}\]", ln)]
+    assert made == [ln for ln in made if " parameter(" in ln]
